@@ -48,8 +48,9 @@ def main():
     for s in sids[:3]:
         print(f"[serve] seq {s}: {eng.result(s)}")
     total_tokens = sum(len(eng.result(s)) for s in sids)
+    kernel = "interpret-mode" if eng.kernel_interpret else "compiled"
     print(f"[serve] throughput: {total_tokens / dt:.1f} tok/s "
-          f"(CPU, interpret-mode kernel)")
+          f"({jax.default_backend()}, {kernel} kernel)")
 
 
 if __name__ == "__main__":

@@ -20,11 +20,12 @@ scale:
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import NotFound, WtfClient
+from repro.core import AlreadyExists, NotFound, WtfClient
 from .serialize import (bytes_to_leaf, decode_manifest, encode_manifest,
                         flatten_tree, leaf_to_bytes, unflatten_tree)
 
@@ -63,7 +64,7 @@ class CheckpointManager:
         if not self.client.exists(step_dir):
             try:
                 self.client.mkdir(step_dir)
-            except Exception:
+            except AlreadyExists:
                 pass                       # another host won the race
 
         prev_manifest = None
@@ -243,8 +244,11 @@ class CheckpointManager:
                     with c.open_file(path, "w") as f:
                         f.paste(_carve(flat_extents, lo, hi - lo))
             new_entries[name] = {**meta, "shards": n}
+        # the manifest's extras (e.g. the data-pipeline cursor) describe
+        # the same training state, so they carry over
+        extra = {k: v for k, v in man.items() if k not in ("leaves", "step")}
         self._commit(dst_step, new_entries,
-                     {"resharded_from": step, "step": dst_step})
+                     {**extra, "resharded_from": step, "step": dst_step})
 
     # ------------------------------------------------------------ retention
     def list_steps(self) -> List[int]:
@@ -301,14 +305,21 @@ class AsyncCheckpointer:
         self._mgr = CheckpointManager(manager.client.cluster.client(),
                                       manager.root, keep=manager.keep)
         self._fut = None
+        # seconds from submission to commit, one entry per finished save
+        self.durations: List[float] = []
 
     def save(self, step: int, tree: Any, **kw) -> None:
         self.wait()
         # Snapshot leaves NOW (cheap on host) so the trainer may mutate.
         snap = {k: np.array(v) for k, v in flatten_tree(tree).items()}
         runtime = self._mgr.client.cluster.runtime
-        self._fut = runtime.submit_op(
-            lambda: self._mgr.save(step, snap, **kw))
+        t0 = time.perf_counter()
+
+        def run():
+            self._mgr.save(step, snap, **kw)
+            self.durations.append(time.perf_counter() - t0)
+
+        self._fut = runtime.submit_op(run)
 
     def wait(self) -> None:
         if self._fut is not None:

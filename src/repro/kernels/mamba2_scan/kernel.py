@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was TPUCompilerParams before the jax rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(xw_ref, dta_ref, b_ref, c_ref, y_ref, sfin_ref, s_ref, *,
             chunk: int, n_chunks: int):
@@ -111,7 +107,7 @@ def ssd_scan_kernel(xw: jax.Array, dta: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xw, dta, b, c)

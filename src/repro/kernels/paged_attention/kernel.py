@@ -25,10 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was TPUCompilerParams before the jax rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -52,9 +48,13 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)          # [G, D]
         k = k_ref[0, 0].astype(jnp.float32)          # [T, D]
         v = v_ref[0, 0].astype(jnp.float32)          # [T, D]
+        # HIGHEST: without it the MXU rounds f32 operands to bf16 (seen
+        # as 7e-3 abs error against the f32 reference on a v5e); decode
+        # attention is bound by the page reads, not by these passes
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [G, T]
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST) * scale  # [G, T]
         pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < length, s, NEG_INF)
 
@@ -64,7 +64,8 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p, v, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
         m_ref[...] = m_new
 
     @pl.when(i == pages - 1)
@@ -116,7 +117,7 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, groups, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table, lengths.astype(jnp.int32), qg, k_pages, v_pages)

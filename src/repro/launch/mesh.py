@@ -17,15 +17,12 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    try:                      # AxisType landed after jax 0.4.x; Auto is the
-        from jax.sharding import AxisType      # default there anyway
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

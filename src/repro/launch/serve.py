@@ -1,6 +1,11 @@
 """Production serve driver: paged-KV continuous-batching engine.
 
-  python -m repro.launch.serve --arch qwen2-7b --requests 16
+  python -m repro.launch.serve --arch smollm-360m --requests 16 [--smoke]
+
+Without ``--smoke`` the model has its published widths (the default
+``smollm-360m`` fits one v5e); ``--smoke`` selects the reduced
+same-family config.  The paged-attention kernel compiles on a TPU and
+runs in interpret mode on any other backend.
 """
 from __future__ import annotations
 
@@ -10,21 +15,26 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import ARCHS, get_smoke_config
+from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serving.engine import Engine, EngineConfig
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCHS, default="qwen2-7b")
+    ap.add_argument("--arch", choices=ARCHS, default="smollm-360m")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--page-tokens", type=int, default=16)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU tests)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_smoke_config(args.arch)
+    cfg = get_smoke_config(args.arch) if args.smoke \
+        else get_config(args.arch)
     if cfg.arch_kind not in ("dense", "vlm"):
         raise SystemExit(f"{args.arch}: paged engine serves the dense "
                          "family; use examples/ for SSM decode")
@@ -33,6 +43,8 @@ def main():
     eng = Engine(model, params, EngineConfig(
         page_tokens=args.page_tokens,
         num_pages=max(1024, args.requests * 64)))
+    print(f"[serve] {cfg.name} on {jax.default_backend()}, paged "
+          f"attention {'interpreted' if eng.kernel_interpret else 'compiled'}")
 
     rng = np.random.RandomState(0)
     t0 = time.time()

@@ -2,10 +2,13 @@
 
   python -m repro.launch.train --arch smollm-360m --steps 200 [--smoke]
 
-On real hardware this process runs per host (jax.distributed); in this
-container it drives the reduced config end-to-end on CPU with the full
-substrate (WTF data pipeline, transactional checkpoints, restart).
-The full-scale configs are exercised via `repro.launch.dryrun`.
+Runs one process with the full substrate: the WTF data pipeline,
+transactional async checkpoints and restart.  Without ``--smoke`` the
+model has its published widths; on a TPU the remat policy is the fastest
+whose compiled step fits the chip's memory (`fit_remat`), so
+``smollm-360m`` trains on one v5e.  ``--smoke`` selects the reduced
+same-family config that CPU tests use.  `chip_smoke.py` at the repository
+root drives this path on the chip and checks its results.
 """
 from __future__ import annotations
 
@@ -19,8 +22,9 @@ from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core import Cluster
 from repro.data.pipeline import DataPipeline, PipelineConfig
 from repro.data.records import write_token_shard
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
-from repro.train import AdamWConfig, TrainHyper
+from repro.train import AdamWConfig, TrainHyper, fit_remat
 from repro.train.trainer import Trainer, TrainerConfig
 
 
@@ -30,16 +34,19 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--smoke", action="store_true", default=True,
-                    help="reduced config (full configs need TPUs; see "
-                    "repro.launch.dryrun)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced same-family config (CPU tests)")
     ap.add_argument("--data-dir", default=None)
     ap.add_argument("--accum", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch)).replace(max_seq=args.seq)
-    model = get_model(cfg)
+    hyper = TrainHyper(adamw=AdamWConfig(warmup_steps=20,
+                                         decay_steps=args.steps),
+                       accum_steps=args.accum)
+    model = fit_remat(get_model(cfg), hyper, args.batch, args.seq)
     data_dir = args.data_dir or tempfile.mkdtemp(prefix="wtf_launch_")
     cluster = Cluster(n_servers=4, data_dir=data_dir, replication=2)
     fs = cluster.client()
@@ -56,10 +63,7 @@ def main():
         block_tokens=args.seq + 1, global_batch=args.batch, seed=0))
     trainer = Trainer(
         model, pipe, CheckpointManager(fs, "/ckpt", keep=3),
-        hyper=TrainHyper(adamw=AdamWConfig(warmup_steps=20,
-                                           decay_steps=args.steps),
-                         accum_steps=args.accum),
-        cfg=TrainerConfig(total_steps=args.steps))
+        hyper=hyper, cfg=TrainerConfig(total_steps=args.steps))
     trainer.run()
     cluster.close()
 

@@ -32,7 +32,6 @@ class EngineConfig:
     num_pages: int = 2048
     max_seqs: int = 64
     max_tokens: int = 512
-    use_kernel_interpret: bool = True     # CPU: Pallas interpret mode
 
 
 @dataclass
@@ -71,9 +70,12 @@ class Engine:
         self._prefill = jax.jit(functools.partial(
             _prefill_step, cfg=self.mcfg,
             page_tokens=cfg.page_tokens))
+        # the Pallas kernel compiles for the TPU; any other backend runs
+        # it in interpret mode
+        self.kernel_interpret = jax.default_backend() != "tpu"
         self._decode = jax.jit(functools.partial(
             _decode_step, cfg=self.mcfg, page_tokens=cfg.page_tokens,
-            interpret=cfg.use_kernel_interpret))
+            interpret=self.kernel_interpret))
 
     # ------------------------------------------------------------ requests
     def add(self, prompt: np.ndarray, max_new: int = 16,

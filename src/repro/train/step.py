@@ -9,13 +9,14 @@ fit HBM).  `make_serve_step` returns (params, cache, batch) ->
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models import Model
+from repro.models import Model, get_model
 from repro.models.layers import cross_entropy
 from . import optimizer as opt
 
@@ -99,6 +100,46 @@ def make_train_step(model: Model, hyper: TrainHyper = TrainHyper(),
                  "step": state["step"] + 1}, metrics)
 
     return train_step
+
+
+REMAT_POLICIES = ("none", "dots", "full")       # fastest first
+
+
+def fit_remat(model: Model, hyper: TrainHyper, batch: int,
+              seq: int) -> Model:
+    """The model under the fastest remat policy whose train step, at
+    ``batch`` x ``seq`` tokens, the compiler fits into the default
+    device's memory; prints each attempt with its compile time.  A
+    backend that reports no memory limit (the CPU) gets the model back
+    unchanged."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if limit is None:
+        return model
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    for remat in REMAT_POLICIES:
+        m = get_model(model.cfg.replace(remat=remat))
+        step = jax.jit(make_train_step(m, hyper), donate_argnums=(0,))
+        t0 = time.perf_counter()
+        try:
+            mem = step.lower(abstract_state(m), {
+                "tokens": tokens, "labels": tokens}).compile() \
+                .memory_analysis()
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            print(f"[fit_remat] remat={remat}: refused in "
+                  f"{time.perf_counter() - t0:.1f}s: "
+                  f"{str(e).splitlines()[0][:160]}", flush=True)
+            continue
+        need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        print(f"[fit_remat] remat={remat}: needs {need / 1e9:.2f} GB of "
+              f"{limit / 1e9:.2f} GB, compiled in "
+              f"{time.perf_counter() - t0:.1f}s", flush=True)
+        if need <= limit:
+            return m
+    raise ValueError(f"{model.cfg.name}: no remat policy fits a "
+                     f"{batch}x{seq} train step in {limit} bytes")
 
 
 def make_serve_step(model: Model, rules=None) -> Callable:

@@ -20,16 +20,20 @@ Fault-tolerance contract (what makes this runnable at 1000+ nodes):
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import AsyncCheckpointer, CheckpointManager
 from repro.data.pipeline import DataPipeline, PipelineConfig, PipelineState
 from repro.models import Model
+from repro.parallel.sharding import make_rules, tree_shardings
 
 from . import optimizer as opt
 from .step import TrainHyper, init_state, make_train_step
@@ -49,7 +53,12 @@ class Trainer:
     def __init__(self, model: Model, pipeline: DataPipeline,
                  ckpt: CheckpointManager, hyper: TrainHyper = TrainHyper(),
                  cfg: TrainerConfig = TrainerConfig(),
-                 rules=None, pod_sync: Optional[Callable] = None):
+                 rules=None, pod_sync: Optional[Callable] = None,
+                 mesh=None):
+        """Without a mesh, state and batches live on the default device.
+        With one, the state is sharded by the model's schema under
+        ``rules`` (derived from the mesh when not given) and each batch
+        is split on its leading axis."""
         self.model = model
         self.pipeline = pipeline
         self.ckpt = ckpt
@@ -63,26 +72,48 @@ class Trainer:
         self.cfg = cfg
         self.hyper = hyper
         self.pod_sync = pod_sync
-        self.train_step = jax.jit(make_train_step(model, hyper, rules),
-                                  donate_argnums=(0,))
+        self.mesh = mesh
+        if mesh is not None and rules is None:
+            rules = make_rules(mesh, **dict(model.cfg.rules_overrides))
+        self.rules = rules
+        step = make_train_step(model, hyper, rules)
+        self._init = functools.partial(init_state, model)
+        if mesh is None:
+            self.state_sharding = None
+            self.train_step = jax.jit(step, donate_argnums=(0,))
+        else:
+            psh = tree_shardings(model.schema(), mesh, rules)
+            rep = NamedSharding(mesh, PartitionSpec())
+            self.state_sharding = {
+                "params": psh, "opt": opt.OptState(m=psh, v=psh, count=rep),
+                "step": rep}
+            bsh = NamedSharding(mesh, PartitionSpec(rules["batch"]))
+            self.train_step = jax.jit(
+                step, in_shardings=(self.state_sharding, bsh),
+                out_shardings=(self.state_sharding, None),
+                donate_argnums=(0,))
         self.history: List[Dict[str, float]] = []
 
     # ------------------------------------------------------------ restart
     def restore_or_init(self):
         """Cold start or restart — one code path, transactional cursor."""
+        key = jax.random.PRNGKey(self.cfg.seed)
         step = self.ckpt.latest_step()
         if step is None:
-            state = init_state(self.model, jax.random.PRNGKey(self.cfg.seed))
-            return state, PipelineState()
+            init = (jax.jit(self._init) if self.mesh is None else jax.jit(
+                self._init, out_shardings=self.state_sharding))
+            return init(key), PipelineState()
         man = self.ckpt.read_manifest(step)
-        template = init_state(self.model, jax.random.PRNGKey(self.cfg.seed))
+        template = jax.eval_shape(self._init, key)
         state = self.ckpt.restore(template, step)
         pstate = PipelineState.from_dict(man.get("pipeline", {
             "epoch": 0, "step_in_epoch": 0}))
-        return state, pstate
+        return jax.device_put(state, self.state_sharding), pstate
 
     # ---------------------------------------------------------------- run
     def run(self) -> Dict[str, Any]:
+        """Train to ``total_steps``; returns the history and the final
+        state."""
         state, pstate = self.restore_or_init()
         start = int(state["step"])
         self.pipeline.state = pstate
@@ -92,7 +123,9 @@ class Trainer:
             raw = next(it)
             batch = {"tokens": raw["tokens"], "labels": raw["labels"]}
             pstate = self.pipeline.state
-            state, metrics = self.train_step(state, batch)
+            with jax.set_mesh(self.mesh) if self.mesh is not None \
+                    else contextlib.nullcontext():
+                state, metrics = self.train_step(state, batch)
             if self.pod_sync is not None and self.cfg.pod_sync_every \
                     and (step + 1) % self.cfg.pod_sync_every == 0:
                 state["params"] = self.pod_sync(state["params"])
@@ -110,7 +143,7 @@ class Trainer:
                 self._save(state, pstate, step + 1)
         self.async_ckpt.wait()
         return {"final_step": self.cfg.total_steps,
-                "history": self.history}
+                "history": self.history, "state": state}
 
     def _save(self, state, pstate: PipelineState, step: int) -> None:
         host_state = jax.tree.map(np.asarray, state)
@@ -123,4 +156,5 @@ class Trainer:
         """Elastic re-scale: same global step, new host topology."""
         return Trainer(self.model, self.pipeline.with_hosts(host_id,
                                                             num_hosts),
-                       self.ckpt, self.hyper, self.cfg)
+                       self.ckpt, self.hyper, self.cfg, rules=self.rules,
+                       mesh=self.mesh)

@@ -118,8 +118,10 @@ def test_zero_copy_reshard(cluster, fs):
     mgr = CheckpointManager(fs)
     big = {"w": np.arange(50_000, dtype=np.float32),
            "small": np.float32(3.0)}
+    cursor = {"epoch": 1, "step_in_epoch": 7}
     for host in range(2):
-        mgr.save(1, big, host_id=host, num_hosts=2)
+        mgr.save(1, big, host_id=host, num_hosts=2,
+                 extra={"pipeline": cursor})
     writes_before = sum(s.stats.bytes_written
                         for s in cluster.servers.values())
     mgr.reshard(1, new_shards=4, dst_step=2)
@@ -131,6 +133,8 @@ def test_zero_copy_reshard(cluster, fs):
     np.testing.assert_array_equal(got["w"], big["w"])
     man = mgr.read_manifest(2)
     assert man["leaves"]["w"]["shards"] == 4
+    # the resharded manifest describes the same training state
+    assert man["pipeline"] == cursor and man["resharded_from"] == 1
 
 
 def test_retention_unlinks_old_steps(fs):
