@@ -97,6 +97,10 @@ LOCK_LEVELS: Tuple[LockLevel, ...] = (
               doc="HealthTracker circuit/EWMA state (innermost leaf: "
                   "consulted from failover walks deep inside data-plane "
                   "rounds; nothing blocks or nests under it)"),
+    LockLevel("trace.rings", 130,
+              doc="the span tracer's rings (innermost leaf: any layer "
+                  "records a span while holding its own locks; nothing "
+                  "is taken under it)"),
 )
 
 LEVEL_BY_NAME: Dict[str, LockLevel] = {lv.name: lv for lv in LOCK_LEVELS}
@@ -117,6 +121,7 @@ STATIC_LOCK_MAP: Dict[Tuple[str, Optional[str], str], str] = {
     ("wlog", "LogConsumer", "_cond"): "wlog.consumer",
     ("iort", "PlanCache", "_lock"): "cache.plan",
     ("iort", "HealthTracker", "_lock"): "iort.health",
+    ("trace", "Tracer", "_lock"): "trace.rings",
     ("repair", "RepairQueue", "_lock"): "repair.queue",
     ("blockcache", "BlockCache", "_lock"): "cache.block",
     ("storage", "_ReadaheadPool", "_lock"): "storage.readahead",

@@ -20,12 +20,14 @@ scale:
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import AlreadyExists, NotFound, WtfClient
+from repro.util import trace
 from .serialize import (bytes_to_leaf, decode_manifest, encode_manifest,
                         flatten_tree, leaf_to_bytes, unflatten_tree)
 
@@ -58,7 +60,19 @@ class CheckpointManager:
         small leaves are written by host 0 alone.  With ``prev_step`` given,
         unchanged leaves are shared with the previous checkpoint via
         ``copy`` instead of rewritten (incremental checkpointing).
+
+        The save is the span ``ckpt.save``.
         """
+        with trace.span("ckpt.save", step=step) as rec:
+            before = self.client.stats.snapshot()
+            stats = self._save(step, tree, host_id, num_hosts, extra,
+                               prev_step)
+            rec.attrs.update(_deltas(self.client, before, _SAVE_COUNTERS),
+                             bytes=stats["bytes_written"])
+        return stats
+
+    def _save(self, step: int, tree: Any, host_id: int, num_hosts: int,
+              extra: Optional[dict], prev_step: Optional[int]) -> dict:
         flat = flatten_tree(tree)
         step_dir = self._step_dir(step)
         if not self.client.exists(step_dir):
@@ -79,10 +93,12 @@ class CheckpointManager:
         # One transaction per host: the host's shard set publishes
         # atomically, and with write-behind every leaf's stores (plus, for
         # a single-host save, the manifest itself) flush through the write
-        # scheduler in ONE planning pass at this commit.
-        with self.client.transaction():
+        # scheduler in ONE planning pass at this commit.  The span
+        # ``ckpt.save.commit`` closes after the transaction's commit.
+        with contextlib.ExitStack() as commit, self.client.transaction():
             for name, leaf in flat.items():
-                data, meta = leaf_to_bytes(leaf)
+                with trace.span("ckpt.save.encode"):
+                    data, meta = leaf_to_bytes(leaf)
                 shards = self._shards_for(meta, num_hosts)
                 meta["shards"] = shards
                 entries[name] = meta
@@ -103,13 +119,15 @@ class CheckpointManager:
                         continue           # not this host's shard
                     lo, hi = self._shard_range(meta["nbytes"], shards, s)
                     path = self._leaf_path(step, name, s, shards)
-                    with self.client.open_file(path, "w") as f:
+                    with trace.span("ckpt.save.write", bytes=hi - lo), \
+                            self.client.open_file(path, "w") as f:
                         # writev: the shard's stores are planned as one
                         # batch and fanned out per region by the write
                         # scheduler (wsched) instead of a single
                         # synchronous store round.
                         f.writev([data[lo:hi]])
                     stats["bytes_written"] += hi - lo
+            commit.enter_context(trace.span("ckpt.save.commit"))
             if host_id == 0 and num_hosts == 1:
                 # Single-host save: shards + manifest + ``latest`` flip
                 # commit (and flush) as one transaction.
@@ -117,7 +135,8 @@ class CheckpointManager:
 
         if host_id == 0:
             if num_hosts > 1:
-                self._commit(step, entries, extra or {})
+                with trace.span("ckpt.save.commit"):
+                    self._commit(step, entries, extra or {})
             if self.keep is not None:
                 self.retain(self.keep)
         return stats
@@ -179,6 +198,27 @@ class CheckpointManager:
         than the sum of all shard reads.  Inside an open transaction
         (async ops are auto-commit only) the shards read synchronously,
         preserving the old join-the-caller's-transaction behavior."""
+        with trace.span("ckpt.restore") as rec:
+            before = self.client.stats.snapshot()
+            with trace.span("ckpt.restore.fetch"):
+                man, parts = self._fetch(step)
+            with trace.span("ckpt.restore.decode"):
+                # Single-shard leaves keep the vectored read's zero-copy
+                # buffer all the way into np.frombuffer; only multi-shard
+                # leaves join.
+                flat = {name: bytes_to_leaf(
+                            ps[0] if len(ps) == 1 else b"".join(ps),
+                            man["leaves"][name])
+                        for name, ps in parts.items()}
+                tree = unflatten_tree(flat, template)
+            rec.attrs.update(
+                _deltas(self.client, before, _RESTORE_COUNTERS),
+                step=man["step"], bytes=sum(
+                    m["nbytes"] for m in man["leaves"].values()))
+        return tree
+
+    def _fetch(self, step: Optional[int]):
+        """The manifest, and every shard's bytes by leaf."""
         man = self.read_manifest(step)
         step = man["step"]
         c = self.client
@@ -206,12 +246,7 @@ class CheckpointManager:
         finally:
             for f in handles:
                 f.close()
-        # Single-shard leaves keep the vectored read's zero-copy buffer
-        # all the way into np.frombuffer; only multi-shard leaves join.
-        flat = {name: bytes_to_leaf(ps[0] if len(ps) == 1 else b"".join(ps),
-                                    man["leaves"][name])
-                for name, ps in parts.items()}
-        return unflatten_tree(flat, template)
+        return man, parts
 
     # ------------------------------------------------------------ reshard
     def reshard(self, step: int, new_shards: int, dst_step: int) -> None:
@@ -271,6 +306,18 @@ class CheckpointManager:
         return victims
 
 
+# the client's counters whose deltas a save's and a restore's span carry
+_SAVE_COUNTERS = ("store_batches", "data_bytes_written")
+_RESTORE_COUNTERS = ("fetch_batches", "block_cache_hits", "plan_cache_hits",
+                     "data_bytes_read")
+
+
+def _deltas(client: WtfClient, before: dict,
+            names: Sequence[str]) -> Dict[str, int]:
+    after = client.stats.snapshot()
+    return {n: after[n] - before[n] for n in names}
+
+
 def _carve(extents: Sequence[Any], start: int, length: int) -> list:
     """Sub-range [start, start+length) of a concatenated extent list."""
     out = []
@@ -309,9 +356,13 @@ class AsyncCheckpointer:
         self.durations: List[float] = []
 
     def save(self, step: int, tree: Any, **kw) -> None:
-        self.wait()
-        # Snapshot leaves NOW (cheap on host) so the trainer may mutate.
-        snap = {k: np.array(v) for k, v in flatten_tree(tree).items()}
+        """Snapshot ``tree`` (span ``ckpt.submit``) and submit its save,
+        whose ``ckpt.save`` nests under the caller's span."""
+        with trace.span("ckpt.submit", step=step) as rec:
+            self.wait()
+            # Snapshot leaves NOW (cheap on host) so the trainer may mutate.
+            snap = {k: np.array(v) for k, v in flatten_tree(tree).items()}
+            rec.attrs["bytes"] = sum(v.nbytes for v in snap.values())
         runtime = self._mgr.client.cluster.runtime
         t0 = time.perf_counter()
 
@@ -324,4 +375,5 @@ class AsyncCheckpointer:
     def wait(self) -> None:
         if self._fut is not None:
             fut, self._fut = self._fut, None
-            fut.result()                    # re-raises a failed save
+            with trace.span("ckpt.wait"):
+                fut.result()                # re-raises a failed save

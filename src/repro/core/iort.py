@@ -51,6 +51,7 @@ directions share, and the pieces the overlap needs:
 """
 from __future__ import annotations
 
+import contextvars
 import itertools
 import queue as _queue
 import threading
@@ -58,6 +59,8 @@ import time
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from repro.util import trace
 
 from .errors import DeadlineExceeded, ReplicaExhausted, StorageError
 from .placement import stable_hash
@@ -628,12 +631,6 @@ class IoRuntime:
         self._ewma_round_s: Optional[float] = None   # fixed per-round cost
         self._ewma_bw: Optional[float] = None        # bytes / second
         self._rounds_observed = 0
-        # Pool admission delay: how long a submitted async op sat queued
-        # before a worker picked it up — the runtime-side analogue of the
-        # KV plane's ``commit_wait_s`` (queueing here means concurrent ops
-        # are serializing on pool capacity, not on locks).
-        self._ewma_op_wait_s: Optional[float] = None
-        self._ops_observed = 0
 
     # ----------------------------------------------------------------- pool
     def _pool_get(self) -> ThreadPoolExecutor:
@@ -754,25 +751,23 @@ class IoRuntime:
         executes on a worker, and the caller's thread is free to plan the
         next op.  ``stats`` is the owning client's ``ClientStats``
         (records ``blocked_waits`` when the caller has to block on the
-        result).
+        result).  ``fn`` runs in a copy of the caller's context, so the
+        spans it opens nest under the caller's open span; its wait for a
+        worker is the first of them, ``io.op_wait``.
         """
         task = IoTask("op")
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
+        ctx = contextvars.copy_context()
+
+        def op():
+            trace.record("io.op_wait", t0, time.perf_counter_ns())
+            return fn()
 
         def body(_t):
-            self._observe_op_wait(time.perf_counter() - t0)
-            return fn()
+            return ctx.run(op)
 
         fut = self._pool_get().submit(self._execute, task, body)
         return IoFuture(fut, stats)
-
-    def _observe_op_wait(self, seconds: float) -> None:
-        with self._model_lock:
-            self._ops_observed += 1
-            prev = self._ewma_op_wait_s
-            self._ewma_op_wait_s = (
-                seconds if prev is None
-                else prev + _EWMA_ALPHA * (seconds - prev))
 
     # ------------------------------------------------------- adaptive model
     def observe_round(self, server_id: Optional[int], seconds: float,
@@ -835,10 +830,7 @@ class IoRuntime:
             rtt = dict(self._rtt_by_server)
             round_s, bw = self._ewma_round_s, self._ewma_bw
             rounds = self._rounds_observed
-            op_wait, ops = self._ewma_op_wait_s, self._ops_observed
         return {
-            "ops_observed": ops,
-            "ewma_op_wait_s": op_wait,
             "adaptive_gap_bytes": self.gap_bytes(),
             "adaptive_coalesce_bytes": self.coalesce_bytes(),
             "gap_pinned": self._gap_override is not None,

@@ -5,7 +5,9 @@
 Without ``--smoke`` the model has its published widths (the default
 ``smollm-360m`` fits one v5e); ``--smoke`` selects the reduced
 same-family config.  The paged-attention kernel compiles on a TPU and
-runs in interpret mode on any other backend.
+runs in interpret mode on any other backend.  At exit it prints, for
+each span of the program, the count and the total, self and longest
+seconds.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serving.engine import Engine, EngineConfig
+from repro.util import trace
 
 
 def main():
@@ -61,6 +64,8 @@ def main():
     print(f"[serve] {args.requests} requests, {tokens} tokens, "
           f"{steps} steps, {dt:.2f}s → {tokens / dt:.1f} tok/s")
     print(f"[serve] page stats: {eng.cache.stats}")
+    for name, row in trace.summary().items():
+        print(f"[trace] {name}: {row}")
 
 
 if __name__ == "__main__":

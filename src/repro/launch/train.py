@@ -8,7 +8,9 @@ model has its published widths; on a TPU the remat policy is the fastest
 whose compiled step fits the chip's memory (`fit_remat`), so
 ``smollm-360m`` trains on one v5e.  ``--smoke`` selects the reduced
 same-family config that CPU tests use.  `chip_smoke.py` at the repository
-root drives this path on the chip and checks its results.
+root drives this path on the chip and checks its results.  At exit it
+prints, for each span of the program, the count and the total, self and
+longest seconds.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.train import AdamWConfig, TrainHyper, fit_remat
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.util import trace
 
 
 def main():
@@ -66,6 +69,8 @@ def main():
         hyper=hyper, cfg=TrainerConfig(total_steps=args.steps))
     trainer.run()
     cluster.close()
+    for name, row in trace.summary().items():
+        print(f"[trace] {name}: {row}")
 
 
 if __name__ == "__main__":

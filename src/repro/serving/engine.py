@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.models import layers as L
+from repro.util import trace
 from .kv_cache import CacheConfig, PagedKVCache
 
 
@@ -82,40 +83,49 @@ class Engine:
             fork_from: Optional[int] = None) -> int:
         """Admit a request.  `fork_from` shares the parent's prefix pages
         (WTF `copy`: refcounted, zero data movement) — only the new suffix
-        is prefilled."""
+        is prefilled.  The admission is the span ``engine.add``."""
         sid = self._next_id
         self._next_id += 1
-        shared = 0
-        if fork_from is not None:
-            self.cache.fork(fork_from, sid)
-            shared = self.cache.seq_len[sid]
-            # only positions past the shared prefix need prefill
-            assert len(prompt) >= shared, "fork prefix longer than prompt"
-            if shared % self.cfg.page_tokens:
-                # shared prefix ends mid-page: COW the open page so the
-                # suffix prefill cannot touch the parent's copy
-                self._cow_page(sid, shared // self.cfg.page_tokens)
-        else:
-            self.cache.create(sid)
-        req = Request(sid, prompt, max_new)
-        if len(prompt) > shared:
-            # the prefill's last-position logits ARE the first output token
-            req.out.append(self._run_prefill(sid, prompt, shared))
-            req.done = len(req.out) >= max_new
-        self._requests[sid] = req
+        with trace.span("engine.add", sid=sid,
+                        prompt_tokens=len(prompt)) as rec:
+            shared = 0
+            if fork_from is not None:
+                with trace.span("engine.fork"):
+                    self.cache.fork(fork_from, sid)
+                shared = self.cache.seq_len[sid]
+                # only positions past the shared prefix need prefill
+                assert len(prompt) >= shared, \
+                    "fork prefix longer than prompt"
+                if shared % self.cfg.page_tokens:
+                    # shared prefix ends mid-page: COW the open page so
+                    # the suffix prefill cannot touch the parent's copy
+                    self._cow_page(sid, shared // self.cfg.page_tokens)
+            else:
+                self.cache.create(sid)
+            rec.attrs["shared_tokens"] = shared
+            req = Request(sid, prompt, max_new)
+            if len(prompt) > shared:
+                # the prefill's last-position logits ARE the first output
+                # token
+                req.out.append(self._run_prefill(sid, prompt, shared))
+                req.done = len(req.out) >= max_new
+            self._requests[sid] = req
         return sid
 
     def _cow_page(self, sid: int, page_idx: int) -> None:
+        """Give ``sid`` its own copy of its page ``page_idx`` where that
+        page is shared (span ``engine.cow``)."""
         tbl = self.cache.page_table[sid]
         pid = tbl[page_idx]
         if self.cache.refcount[pid] <= 1:
             return
-        new = self.cache._alloc_page()
-        self.k_pool = self.k_pool.at[:, new].set(self.k_pool[:, pid])
-        self.v_pool = self.v_pool.at[:, new].set(self.v_pool[:, pid])
-        self.cache._release_page(pid)
-        tbl[page_idx] = new
-        self.cache.stats["pages_copied"] += 1
+        with trace.span("engine.cow"):
+            new = self.cache._alloc_page()
+            self.k_pool = self.k_pool.at[:, new].set(self.k_pool[:, pid])
+            self.v_pool = self.v_pool.at[:, new].set(self.v_pool[:, pid])
+            self.cache._release_page(pid)
+            tbl[page_idx] = new
+            self.cache.stats["pages_copied"] += 1
 
     def _ensure_pages(self, sid: int, upto: int) -> None:
         t = self.cfg.page_tokens
@@ -136,41 +146,39 @@ class Engine:
             jnp.asarray(self.scratch_page, jnp.int32))
         self.k_pool, self.v_pool = kp, vp
         self.cache.seq_len[sid] = n
-        return int(next_tok[0])
+        with trace.span("engine.prefill.wait"):
+            return int(next_tok[0])
 
     # --------------------------------------------------------------- step
     def step(self) -> List[int]:
-        """One decode step for every active sequence; returns finished ids."""
-        active = [r for r in self._requests.values() if not r.done]
-        if not active:
-            return []
-        b = len(active)
-        tokens = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        for i, r in enumerate(active):
-            tokens[i, 0] = r.out[-1]       # prefill seeded out[0]
-            pos[i] = self.cache.seq_len[r.seq_id]
-            # COW before writing into a shared open page
-            t = self.cfg.page_tokens
-            tbl = self.cache.page_table[r.seq_id]
-            self._ensure_pages(r.seq_id, int(pos[i]) + 1)
-            pid = tbl[int(pos[i]) // t]
-            if self.cache.refcount[pid] > 1:
-                new = self.cache._alloc_page()
-                self.k_pool = self.k_pool.at[:, new].set(
-                    self.k_pool[:, pid])
-                self.v_pool = self.v_pool.at[:, new].set(
-                    self.v_pool[:, pid])
-                self.cache._release_page(pid)
-                tbl[int(pos[i]) // t] = new
-                self.cache.stats["pages_copied"] += 1
+        """One decode step for every active sequence; returns finished ids.
+        The step is the span ``engine.step``, whose ``batch`` is 0 where
+        no sequence was active."""
+        with trace.span("engine.step") as rec:
+            active = [r for r in self._requests.values() if not r.done]
+            rec.attrs["batch"] = len(active)
+            return self._step(active, rec) if active else []
 
-        tbl_arr, _ = self.cache.table_array([r.seq_id for r in active])
+    def _step(self, active: List[Request], rec: trace.Record) -> List[int]:
+        with trace.span("engine.step.prepare"):
+            b = len(active)
+            t = self.cfg.page_tokens
+            tokens = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            for i, r in enumerate(active):
+                tokens[i, 0] = r.out[-1]       # prefill seeded out[0]
+                pos[i] = self.cache.seq_len[r.seq_id]
+                self._ensure_pages(r.seq_id, int(pos[i]) + 1)
+                # COW before writing into a shared open page
+                self._cow_page(r.seq_id, int(pos[i]) // t)
+            tbl_arr, _ = self.cache.table_array([r.seq_id for r in active])
+        rec.attrs["table_width"] = tbl_arr.shape[1]
         next_tok, self.k_pool, self.v_pool = self._decode(
             self.params, self.k_pool, self.v_pool,
             jnp.asarray(tokens), jnp.asarray(pos),
             jnp.asarray(np.maximum(tbl_arr, 0)))
-        next_tok = np.asarray(next_tok)
+        with trace.span("engine.step.wait"):
+            next_tok = np.asarray(next_tok)
         finished = []
         for i, r in enumerate(active):
             self.cache.seq_len[r.seq_id] += 1

@@ -34,6 +34,7 @@ from repro.checkpoint import AsyncCheckpointer, CheckpointManager
 from repro.data.pipeline import DataPipeline, PipelineConfig, PipelineState
 from repro.models import Model
 from repro.parallel.sharding import make_rules, tree_shardings
+from repro.util import trace
 
 from . import optimizer as opt
 from .step import TrainHyper, init_state, make_train_step
@@ -103,19 +104,27 @@ class Trainer:
             init = (jax.jit(self._init) if self.mesh is None else jax.jit(
                 self._init, out_shardings=self.state_sharding))
             return init(key), PipelineState()
-        man = self.ckpt.read_manifest(step)
-        template = jax.eval_shape(self._init, key)
-        state = self.ckpt.restore(template, step)
-        pstate = PipelineState.from_dict(man.get("pipeline", {
-            "epoch": 0, "step_in_epoch": 0}))
-        return jax.device_put(state, self.state_sharding), pstate
+        with trace.span("trainer.restore", step=step):
+            man = self.ckpt.read_manifest(step)
+            template = jax.eval_shape(self._init, key)
+            state = self.ckpt.restore(template, step)
+            pstate = PipelineState.from_dict(man.get("pipeline", {
+                "epoch": 0, "step_in_epoch": 0}))
+            with trace.span("trainer.device_put"):
+                return jax.device_put(state, self.state_sharding), pstate
 
     # ---------------------------------------------------------------- run
     def run(self) -> Dict[str, Any]:
         """Train to ``total_steps``; returns the history and the final
         state."""
+        with trace.span("trainer.run") as rec:
+            state = self._run(rec)
+        return {"final_step": self.cfg.total_steps,
+                "history": self.history, "state": state}
+
+    def _run(self, rec: trace.Record):
         state, pstate = self.restore_or_init()
-        start = int(state["step"])
+        start = rec.attrs["step"] = int(state["step"])
         self.pipeline.state = pstate
         it = iter(self.pipeline)
         t_last = time.time()
@@ -142,14 +151,20 @@ class Trainer:
             if (step + 1) % self.cfg.ckpt_every == 0:
                 self._save(state, pstate, step + 1)
         self.async_ckpt.wait()
-        return {"final_step": self.cfg.total_steps,
-                "history": self.history, "state": state}
+        return state
 
     def _save(self, state, pstate: PipelineState, step: int) -> None:
-        host_state = jax.tree.map(np.asarray, state)
-        self.async_ckpt.save(step, host_state,
-                             extra={"pipeline": pstate.to_dict()},
-                             prev_step=self.ckpt.latest_step())
+        """Copy the state to the host and hand it to the asynchronous
+        checkpointer.  The copy waits for the queued steps to finish;
+        ``trainer.save.drain`` shows that wait on its own."""
+        with trace.span("trainer.save", step=step):
+            with trace.span("trainer.save.drain"):
+                jax.block_until_ready(state)
+            with trace.span("trainer.save.d2h"):
+                host_state = jax.tree.map(np.asarray, state)
+            self.async_ckpt.save(step, host_state,
+                                 extra={"pipeline": pstate.to_dict()},
+                                 prev_step=self.ckpt.latest_step())
 
     # -------------------------------------------------------------- elastic
     def with_hosts(self, host_id: int, num_hosts: int) -> "Trainer":
