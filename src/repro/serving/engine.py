@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.paged_attention.kernel import paged_attention_kernel
+from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
+                                                  pages_per_block)
 from repro.models import layers as L
 from repro.util import trace
 from .kv_cache import CacheConfig, PagedKVCache
@@ -153,7 +154,9 @@ class Engine:
     def step(self) -> List[int]:
         """One decode step for every active sequence; returns finished ids.
         The step is the span ``engine.step``, whose ``batch`` is 0 where
-        no sequence was active."""
+        no sequence was active; else it carries the page table's width
+        and the paged kernel's live and spanned blocks (``kv_blocks``,
+        ``kv_blocks_grid``)."""
         with trace.span("engine.step") as rec:
             active = [r for r in self._requests.values() if not r.done]
             rec.attrs["batch"] = len(active)
@@ -172,7 +175,16 @@ class Engine:
                 # COW before writing into a shared open page
                 self._cow_page(r.seq_id, int(pos[i]) // t)
             tbl_arr, _ = self.cache.table_array([r.seq_id for r in active])
-        rec.attrs["table_width"] = tbl_arr.shape[1]
+        # the paged kernel's blocks, one a KV head: those it walks (each
+        # sequence's up to its length after this step's token) and those
+        # its grid spans
+        width = tbl_arr.shape[1]
+        hkv = self.mcfg.n_kv_heads
+        ppb = pages_per_block(t, hkv, self.mcfg.head_dim_,
+                              self.k_pool.dtype.itemsize, width)
+        rec.attrs["table_width"] = width
+        rec.attrs["kv_blocks"] = hkv * int(np.sum(-(-(pos + 1) // (ppb * t))))
+        rec.attrs["kv_blocks_grid"] = hkv * b * -(-width // ppb)
         next_tok, self.k_pool, self.v_pool = self._decode(
             self.params, self.k_pool, self.v_pool,
             jnp.asarray(tokens), jnp.asarray(pos),

@@ -69,19 +69,33 @@ def test_flash_attention_grad_matches_ref():
 
 
 # -------------------------------------------------------- paged attention
-@pytest.mark.parametrize("b,h,hkv,d,pages,t,pp", [
-    (3, 8, 2, 64, 32, 16, 8),
-    (2, 4, 4, 128, 16, 32, 4),
-    (1, 16, 1, 64, 64, 16, 16),   # MQA, long table
+# SmolLM's decode (Hkv 5, G 3, D 64, T 16) runs blocks of 16 pages, 256
+# tokens; explicit lengths place sequence ends against those blocks
+@pytest.mark.parametrize("b,h,hkv,d,pages,t,pp,lens", [
+    pytest.param(3, 8, 2, 64, 32, 16, 8, None, id="3-8-2-64-32-16-8"),
+    pytest.param(2, 4, 4, 128, 16, 32, 4, None, id="2-4-4-128-16-32-4"),
+    pytest.param(1, 16, 1, 64, 64, 16, 16, None,   # MQA, long table
+                 id="1-16-1-64-64-16-16"),
+    # 24 pages: one whole block and one of 8 pages padded to 16
+    pytest.param(4, 15, 5, 64, 128, 16, 24, None, id="smollm-width-24"),
+    pytest.param(2, 15, 5, 64, 64, 16, 32, [256, 1],
+                 id="smollm-one-block-and-one-token"),
+    # the short sequence's second and third blocks lie past its end; the
+    # long one ends inside its third block
+    pytest.param(2, 15, 5, 64, 64, 16, 40, [20, 600],
+                 id="smollm-short-and-long"),
+    # one block, most of whose table entries are the unused tail (-1)
+    pytest.param(1, 15, 5, 64, 32, 16, 16, [37], id="smollm-unused-tail"),
 ])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_attention_sweep(b, h, hkv, d, pages, t, pp, dtype):
+def test_paged_attention_sweep(b, h, hkv, d, pages, t, pp, lens, dtype):
     ks = jax.random.split(RNG, 4)
     q = jax.random.normal(ks[0], (b, h, d)).astype(dtype)
     kp = jax.random.normal(ks[1], (pages, t, hkv, d)).astype(dtype)
     vp = jax.random.normal(ks[2], (pages, t, hkv, d)).astype(dtype)
     rng = np.random.RandomState(0)
-    lengths = rng.randint(1, pp * t + 1, size=b).astype(np.int32)
+    lengths = (rng.randint(1, pp * t + 1, size=b) if lens is None
+               else np.array(lens)).astype(np.int32)
     tbl = np.full((b, pp), -1, np.int32)
     free = list(rng.permutation(pages))
     for i, ln in enumerate(lengths):

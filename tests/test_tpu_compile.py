@@ -62,15 +62,22 @@ def _hbm_need(compiled) -> int:
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("dtype,batch,pages", [
-    ("bfloat16", smoke.SERVE_BATCH, smoke.SERVE_TABLE_PAGES),
-    ("float32", 1, -(-(smoke.PROMPT_LEN + smoke.MAX_NEW - 1)
-                     // smoke.PAGE_TOKENS)),
+@pytest.mark.parametrize("dtype,batch,pages,pool_pages", [
+    pytest.param("bfloat16", smoke.SERVE_BATCH, smoke.SERVE_TABLE_PAGES,
+                 smoke.NUM_PAGES,
+                 id=f"bfloat16-{smoke.SERVE_BATCH}-{smoke.SERVE_TABLE_PAGES}"),
+    pytest.param("float32", 1, -(-(smoke.PROMPT_LEN + smoke.MAX_NEW - 1)
+                                 // smoke.PAGE_TOKENS), smoke.NUM_PAGES,
+                 id="float32-1-4"),
+    # the serving benchmark's batch, pool and widest table: six blocks of
+    # 16 pages for 48 sequences
+    pytest.param("bfloat16", 48, 96, 5120, id="bfloat16-48-96-serve"),
 ])
-def test_paged_attention_kernel_compiles(one_chip, dtype, batch, pages):
+def test_paged_attention_kernel_compiles(one_chip, dtype, batch, pages,
+                                         pool_pages):
     cfg = get_config(smoke.ARCH)
     d, hkv = cfg.head_dim_, cfg.n_kv_heads
-    pool = (hkv, smoke.NUM_PAGES, smoke.PAGE_TOKENS, d)
+    pool = (hkv, pool_pages, smoke.PAGE_TOKENS, d)
     compiled = jax.jit(paged_attention_kernel).lower(
         _spec(one_chip, (batch, cfg.n_heads, d), dtype),
         _spec(one_chip, pool, dtype), _spec(one_chip, pool, dtype),

@@ -217,8 +217,29 @@ def test_engine_add_and_step_spans():
     for name in ("engine.fork", "engine.cow", "engine.prefill.wait"):
         assert len(trace.children(fork, name)) == 1, name
     step, idle = trace.records("engine.step")
-    assert step.attrs == {"batch": 1, "table_width": 3}
+    # 10 tokens in one block of the table's 3 pages, for the one KV head
+    assert step.attrs == {"batch": 1, "table_width": 3, "kv_blocks": 1,
+                          "kv_blocks_grid": 1}
     assert idle.attrs == {"batch": 0} and not trace.children(idle)
     (prepare,) = trace.children(step, "engine.step.prepare")
     (wait,) = trace.children(step, "engine.step.wait")
     assert prepare.end_ns <= wait.start_ns
+
+
+def test_engine_step_kv_blocks_end_mid_block():
+    """Pages of 128 tokens make blocks of two pages: after the step the
+    long sequence (301 tokens) ends inside its second block, and the
+    short one's second block lies wholly past its end."""
+    cfg = get_smoke_config("smollm-360m").replace(
+        compute_dtype="float32", n_heads=4, n_kv_heads=2)
+    model = get_model(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)),
+                 EngineConfig(page_tokens=128, num_pages=8))
+    rng = np.random.RandomState(0)
+    for n in (5, 300):
+        eng.add(rng.randint(0, cfg.vocab, n).astype(np.int32), max_new=2)
+    eng.step()
+    (step,) = trace.records("engine.step")
+    assert step.attrs == {"batch": 2, "table_width": 3,
+                          "kv_blocks": 2 * (1 + 2),
+                          "kv_blocks_grid": 2 * 2 * 2}
