@@ -1,5 +1,4 @@
-"""Helpers every driver shares: the log, seeds, the program's model
-configuration for a configuration file, leaves by path and their
+"""Helpers every driver shares: the log, seeds, leaves by path and their
 checksum, host spans, and the record a per-layer metric reads."""
 from __future__ import annotations
 
@@ -13,9 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config
-
-from chipbench import reference
 from chipbench.device_trace import span
 
 
@@ -29,18 +25,6 @@ def sub_seeds(seed: int) -> Dict[str, int]:
     s = np.random.SeedSequence(seed).generate_state(3)
     return {"data": int(s[0]), "init": int(s[1] >> 1),
             "shuffle": int(s[2] >> 1)}
-
-
-def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file: the registered
-    architecture with the file's published sizes and pinned options."""
-    dm = reference.Dims.from_hf(conf)
-    return get_config(conf["program"]["arch"]).replace(
-        n_layers=dm.layers, d_model=dm.d, n_heads=dm.heads,
-        n_kv_heads=dm.kv_heads, head_dim=dm.head_dim, d_ff=dm.d_ff,
-        vocab=dm.vocab, qkv_bias=dm.qkv_bias, tie_embeddings=dm.tied,
-        rope_theta=dm.theta, norm_eps=dm.eps,
-        **conf["program"].get("options", {}))
 
 
 def flat(tree) -> Dict[str, jax.Array]:

@@ -34,9 +34,9 @@ from repro.models import get_model
 from repro.train import AdamWConfig, TrainHyper
 from repro.train.trainer import Trainer, TrainerConfig
 
-from chipbench import check, device_trace, reference, work
+from chipbench import check, device_trace, reference, spec
 from chipbench.common import (RunData, Spans, fingerprint, flat, log,
-                              model_config, sub_seeds)
+                              sub_seeds)
 from chipbench.device_trace import span
 from chipbench.drivers.train import (CORPUS, SPARE_STEPS, PipelineProbe,
                                      StepProbe, _calibration, _rows_of,
@@ -64,7 +64,7 @@ class InputProbe(StepProbe):
         self.restored_fp = None
 
     def __call__(self, state, batch):
-        if not self.losses:
+        if not self.metrics:
             self.restored_fp = fingerprint(flat(state))
         return super().__call__(state, batch)
 
@@ -75,8 +75,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     the result is the program's numbers and, in its place, the control's
     and a half batch's (`train._calibration`)."""
     tr, conf = cell.traffic, cell.config
-    dm = reference.Dims.from_hf(conf)
-    cfg = model_config(conf)
+    fam = spec.family(conf)
+    dm, cfg = fam.dims(conf), fam.program_config(conf)
     B, S, k = tr["batch"], tr["seq"], tr["ckpt_steps"]
     seeds = sub_seeds(seed)
     hp = dict(tr["optimizer"])
@@ -142,7 +142,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                 "s": time.perf_counter() - t0,
                 "loss": out["history"][-1]["loss"],
                 "cursor": pipe.states_set[0].to_dict(),
-                "fp": probe.restored_fp, "batches": pipe.batches})
+                "fp": probe.restored_fp, "batches": pipe.batches,
+                "metrics": probe.metrics[-1]})
             return out["state"]["params"]
 
         resume()            # warms the restarted trainer up
@@ -176,8 +177,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         if not numbers["rows_not_from_corpus"]:
             fed, _ = _rows_of(fed_setup + resumes[1]["batches"][:1], corpus)
             rows = [_reference_rows(corpus, idx) for idx in fed]
-            ref = reference.run_steps(dm, seeds["init"], rows, hp, k + 1,
-                                      p3_of=p_last)
+            ref = reference.run_steps(fam, dm, seeds["init"], rows, hp,
+                                      k + 1, p3_of=p_last)
             # the set-up steps, and the window's resume farthest from the
             # reference's step k + 1
             prog = {"losses": setup_losses + [max(
@@ -192,13 +193,14 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
             log(f"reference losses {ref['losses']}; resumed "
                 f"{window_losses}")
             if calibrate:
-                return _calibration(dm, seeds, rows, hp, k + 1, numbers,
-                                    ref)
+                return _calibration(fam, dm, seeds, rows, hp, k + 1,
+                                    numbers, ref)
         dev = jax.devices()
         data = RunData(
             resumes=n, restore_s=spans.durations("ckpt.restore", opened),
-            window_steps=n, flops_per_step=work.train_flops_per_token(
-                dm, S) * B * S, trace=summary)
+            window_steps=n, flops_per_step=fam.train_flops_per_step(
+                dm, B, S, [r["metrics"] for r in resumes[1:]]),
+            trace=summary)
         return {
             "numbers": numbers, "data": data,
             "e2e": {"resume_s": (closed - opened) / n,

@@ -54,8 +54,8 @@ import numpy as np
 from repro.models import get_model
 from repro.serving.engine import Engine, EngineConfig
 
-from chipbench import device_trace, reference
-from chipbench.common import RunData, Spans, log, model_config, sub_seeds
+from chipbench import device_trace, reference, spec
+from chipbench.common import RunData, Spans, log, sub_seeds
 from chipbench.device_trace import span
 
 CONTROL_DTYPE = "float8_e4m3fn"   # below the engine's bfloat16 compute
@@ -106,8 +106,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     """With `calibrate`, the check's sample is drawn from wave 0 and the
     result is the program's `served_gap` and the control's."""
     tr, conf, params = cell.traffic, cell.config, cell.params
-    dm = reference.Dims.from_hf(conf)
-    cfg = model_config(conf)
+    fam = spec.family(conf)
+    dm, cfg = fam.dims(conf), fam.program_config(conf)
     seeds = sub_seeds(seed)
     n_sess, turns, new = tr["sessions"], tr["turns"], tr["new_tokens"]
     lengths = prompt_lengths(tr)
@@ -140,7 +140,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         stack.callback(jax.monitoring.unregister_event_duration_listener,
                        on_compile)
         window = stack.enter_context(contextlib.ExitStack())
-        params_ = nest(reference.init_params(dm, seeds["init"]))
+        params_ = nest(reference.init_params(fam, dm, seeds["init"]))
         eng = Engine(get_model(cfg), params_, EngineConfig(
             page_tokens=tr["page_tokens"], num_pages=tr["num_pages"]))
         sys_sid = eng.add(system, max_new=1)
@@ -222,7 +222,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
     sample = _sample(checked, turns, params["checked_per_turn"],
                      seeds["shuffle"])
     t0 = time.perf_counter()
-    gaps = _gaps(dm, seeds["init"], sample, calibrate)
+    gaps = _gaps(fam, dm, seeds["init"], sample, calibrate)
     log(f"reference over {len(sample)} turns "
         f"({sum(len(t['answer']) for t in sample)} served tokens) in "
         f"{time.perf_counter() - t0:.1f}s: {gaps}")
@@ -239,7 +239,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         add_s=spans.durations("engine.add", opened),
         prompt_tokens=sum(len(t["prompt"]) for t in turns_done),
         pages_shared=pages_shared, page_tokens=tr["page_tokens"],
-        steps=steps, dims=dm, trace=summary)
+        steps=steps, dims=dm, family=fam, trace=summary)
     return {
         "numbers": numbers, "data": data,
         "e2e": {**e2e, "setup_s": opened - t_start},
@@ -278,11 +278,12 @@ def _wider(worst: float, gaps: jax.Array, n: int) -> float:
     return max(worst, float(g.max())) if np.isfinite(g).all() else math.inf
 
 
-def _gaps(dm, init_seed: int, sample: List[dict], control: bool) -> dict:
+def _gaps(fam, dm, init_seed: int, sample: List[dict],
+          control: bool) -> dict:
     """The widest gap of a served token below the reference's best, over
     the sampled turns; and the control's, where asked.  A turn with no
     token served is `turns_unfinished`'s."""
-    p = reference.init_params(dm, init_seed)
+    p = reference.init_params(fam, dm, init_seed)
     worst = {"program": 0.0, "control": 0.0}
     for t in sample:
         n, answer = len(t["prompt"]), np.asarray(t["answer"], np.int32)
@@ -291,11 +292,11 @@ def _gaps(dm, init_seed: int, sample: List[dict], control: bool) -> dict:
         seq = np.concatenate([t["prompt"], answer[:-1]])
         served = np.zeros_like(seq)
         served[n - 1:] = answer
-        g = reference.token_gaps(p, jnp.asarray(seq), dm,
+        g = reference.token_gaps(fam, p, jnp.asarray(seq), dm,
                                  jnp.asarray(served))
         worst["program"] = _wider(worst["program"], g, n)
         if control:
-            g = reference.token_gaps(p, jnp.asarray(seq), dm,
+            g = reference.token_gaps(fam, p, jnp.asarray(seq), dm,
                                      jnp.asarray(served),
                                      round_to=CONTROL_DTYPE)
             worst["control"] = _wider(worst["control"], g, n)

@@ -15,9 +15,11 @@ over the window.
 The benchmark hands the trainer the real pipeline behind a wrapper (a
 span around each `next()`, whose start times also give the loop's stall
 in the iteration that saved) and wraps the public jitted step
-`Trainer.train_step` (each step's loss; the optimizer's first moment
-after step 1, the weights after the last set-up step, and a checksum of
-the state the trainer then saves).
+`Trainer.train_step` (each step's loss and metrics; the optimizer's
+first moment after step 1, the weights after the last set-up step, and a
+checksum of the state the trainer then saves).  The model is reached
+through the configuration's family (`families/`): its sizes, the
+program's configuration, the reference and the step's FLOPs.
 """
 from __future__ import annotations
 
@@ -39,9 +41,9 @@ from repro.models import get_model
 from repro.train import AdamWConfig, TrainHyper
 from repro.train.trainer import Trainer, TrainerConfig
 
-from chipbench import check, device_trace, reference, work
+from chipbench import check, device_trace, reference, spec
 from chipbench.common import (RunData, Spans, fingerprint, flat, log,
-                              model_config, sub_seeds)
+                              sub_seeds)
 from chipbench.device_trace import span
 
 CORPUS = "/corpus/shard0"
@@ -89,25 +91,30 @@ class PipelineProbe:
 
 class StepProbe:
     """The trainer's jitted step; records what the reference needs from
-    the set-up steps, every step's loss, and a checksum of the state
-    after step `save_step` (the state the trainer then saves).  After
-    each step it calls `on_step` with the step's number."""
+    the set-up steps, every step's metrics (its loss among them), and a
+    checksum of the state after step `save_step` (the state the trainer
+    then saves).  After each step it calls `on_step` with the step's
+    number."""
 
     def __init__(self, step, warm: int, spans: Spans,
                  save_step: Optional[int] = None,
                  on_step: Optional[Callable[[int], None]] = None):
         self._step, self._warm, self._spans = step, warm, spans
         self._save_step, self._on_step = save_step, on_step
-        self.losses: List[jax.Array] = []
+        self.metrics: List[dict] = []      # what each step returned
         self.m1_norms = None
         self.params_after_warm: Optional[dict] = None
         self.saved_fp = None
 
+    @property
+    def losses(self) -> List[jax.Array]:
+        return [m["loss"] for m in self.metrics]
+
     def __call__(self, state, batch):
         with self._spans("step.dispatch"):
             state, metrics = self._step(state, batch)
-        self.losses.append(metrics["loss"])
-        n = len(self.losses)
+        self.metrics.append(metrics)
+        n = len(self.metrics)
         if n == 1:
             self.m1_norms = reference.leaf_norms(flat(state["opt"].m))
         if n == self._warm:
@@ -178,8 +185,8 @@ def _reference_rows(corpus: np.ndarray, idx: List[int]):
 def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         calibrate: bool = False) -> dict:
     tr, conf, params = cell.traffic, cell.config, cell.params
-    dm = reference.Dims.from_hf(conf)
-    cfg = model_config(conf)
+    fam = spec.family(conf)
+    dm, cfg = fam.dims(conf), fam.program_config(conf)
     B, S, warm = tr["batch"], tr["seq"], tr["warm_steps"]
     steps = 0 if calibrate else max(
         2, round(seconds / params["nominal_step_s"]))
@@ -298,7 +305,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
                 trainer.ckpt, template, save_step, probe.saved_fp))
         data = RunData(
             window_steps=steps, tokens_per_step=B * S,
-            flops_per_step=work.train_flops_per_token(dm, S) * B * S,
+            flops_per_step=fam.train_flops_per_step(
+                dm, B, S, probe.metrics[warm:]),
             pipeline_wait_s=spans.durations("pipeline.next",
                                             win.get("open", math.inf)),
             save_stall_s=None if save_step is None else loop_stall(
@@ -311,15 +319,16 @@ def run(cell, seed: int, seconds: float, trace: bool, t_start: float,
         if not bad_rows:
             rows = [_reference_rows(corpus, fed[i]) for i in range(warm)]
             t0 = time.perf_counter()
-            ref = reference.run_steps(dm, seeds["init"], rows, hp, warm,
-                                      p3_of=p_warm)
+            ref = reference.run_steps(fam, dm, seeds["init"], rows, hp,
+                                      warm, p3_of=p_warm)
             prog["change_norms"] = ref.pop("prog_change_norms")
             del p_warm
             numbers.update(check.train_numbers(prog, ref))
             log(f"reference: {warm} steps in "
                 f"{time.perf_counter() - t0:.1f}s; losses {ref['losses']}")
             if calibrate:
-                return _calibration(dm, seeds, rows, hp, warm, numbers, ref)
+                return _calibration(fam, dm, seeds, rows, hp, warm,
+                                    numbers, ref)
         dev = jax.devices()
         return {
             "numbers": numbers, "data": data,
@@ -362,15 +371,15 @@ def _check_checkpoint(mgr, template, step: int, saved_fp) -> dict:
             "ckpt_step_differs": float(man["step"] != step)}
 
 
-def _calibration(dm, seeds, rows, hp, warm, numbers, ref) -> dict:
+def _calibration(fam, dm, seeds, rows, hp, warm, numbers, ref) -> dict:
     """The program's numbers and, in its place, the control (the
     reference below the stated precision) and a half batch."""
     half = [(t[: len(t) // 2], l[: len(l) // 2]) for t, l in rows]
     out = {"program": numbers}
     for name, kw in (("control", {"rows": rows, "round_to": "float8_e4m3fn"}),
                      ("half_batch", {"rows": half})):
-        other = reference.run_steps(dm, seeds["init"], kw["rows"], hp, warm,
-                                    round_to=kw.get("round_to"))
+        other = reference.run_steps(fam, dm, seeds["init"], kw["rows"], hp,
+                                    warm, round_to=kw.get("round_to"))
         out[name] = check.train_numbers(other, ref)
         log(f"{name}: {out[name]}")
     return out

@@ -1,6 +1,7 @@
 """Model FLOP/s utilization of the whole window: the model FLOPs of the
-window's steps (`work.train_flops_per_token`, no recomputation) over the
-traced window's length times the chip's bf16 peak (`peaks.json`)."""
+window's steps (the family's `train_flops_per_step`, for dense
+`dense.train_flops_per_token`; no recomputation) over the traced window's
+length times the chip's bf16 peak (`peaks.json`)."""
 
 
 def read(run):

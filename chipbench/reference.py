@@ -1,32 +1,33 @@
-"""Plain reference of a dense decoder-only transformer (the Llama and
-Qwen2 families) and of AdamW, for the benchmark's correctness check.
+"""Plain reference for the benchmark's correctness check: the weight
+recipe, a model's loss and gradient one sequence at a time, and AdamW.
+The model itself, its sizes, weights and forward pass, is its family's
+(`families/<family>.py`); this module knows no model's shape.
 
 Written from the published descriptions, in straightforward `jax.numpy`
 at float32 with every matrix product at HIGHEST precision.  It imports
 nothing from the program under test and takes nothing the program made:
 its weights come from the seed by the weight recipe below, its rows from
-the benchmark's own copy of the corpus.
+the benchmark's own copy of the corpus.  A family's forward pass is
+written the same way, with the helpers here (`_rounder`, `_rms`,
+`_rope`, `cross_entropy`).
 
-* Block (HF `LlamaDecoderLayer` / `Qwen2DecoderLayer`): x += attn(rms(x));
-  x += down(silu(gate(rms(x))) * up(rms(x))).  RMSNorm is
-  x * rsqrt(mean(x^2) + eps) * w.  Rotary embedding on q and k with
-  inv_freq = theta^(-2i/head_dim), halves rotated (`rotate_half`).  GQA:
-  query head h reads kv head h // (heads / kv_heads).  Qwen2 adds biases
-  to q, k and v.  Causal softmax attention, scaled by 1/sqrt(head_dim).
-* Output: rms(x) @ W^T with W the unembedding, or the embedding where the
-  two are tied; the loss is the mean token cross-entropy.
-* Weight recipe (the benchmark's, from the seed): the leaves, named as
-  below and taken in sorted name order, each get one key of
-  `jax.random.split(key, n_leaves)`; embeddings are normal * 0.02,
-  matrices normal / sqrt(fan_in), norm scales ones, biases zeros.  Layer
-  leaves are stacked on a leading layer axis.
+* Weight recipe (the benchmark's, from the seed): the leaves of the
+  family's `leaf_specs`, taken in sorted name order, each get one key of
+  `jax.random.split(key, n_leaves)`; "normal" leaves (embeddings) are
+  normal * 0.02, "scaled" ones (matrices) normal / sqrt(fan_in), "ones"
+  and "zeros" (norm scales, biases) constant.  Layer leaves are stacked
+  on a leading layer axis.
+* Loss: the mean over the rows of the family's `row_loss`.  The per-row
+  statistics it returns are summed over the rows and handed, after each
+  step's update, to the family's `after_update`, which moves the leaves
+  no gradient moves (`non_gradient_leaves`); AdamW leaves those alone.
 * AdamW (Loshchilov & Hutter) after clipping the global gradient norm;
   bias-corrected moments; decoupled weight decay on matrices only; linear
   warm-up from 0, then cosine decay to `min_lr_ratio` of the peak.
 
-Memory: the gradient is accumulated one sequence at a time, each layer
-is recomputed in the backward pass, and the AdamW moments wait on the
-host between steps, so that the reference fits one chip.
+Memory: the gradient is accumulated one sequence at a time, and the
+AdamW moments wait on the host between steps, so that the reference fits
+one chip.
 
 `round_to` puts every matrix product's operands (and the embedded tokens)
 through a lower precision on the forward pass: the benchmark's control,
@@ -34,7 +35,6 @@ the reference computed below the precision the configuration states.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
 from functools import partial
 from typing import Dict, Optional
 
@@ -45,63 +45,9 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-@dataclass(frozen=True)
-class Dims:
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    eps: float
-    theta: float
-    tied: bool
-    qkv_bias: bool
-
-    @staticmethod
-    def from_hf(hf: dict) -> "Dims":
-        """From a HF `config.json` of the Llama or Qwen2 families."""
-        heads = hf["num_attention_heads"]
-        return Dims(
-            layers=hf["num_hidden_layers"], d=hf["hidden_size"],
-            heads=heads, kv_heads=hf["num_key_value_heads"],
-            head_dim=hf.get("head_dim") or hf["hidden_size"] // heads,
-            d_ff=hf["intermediate_size"], vocab=hf["vocab_size"],
-            eps=hf["rms_norm_eps"], theta=hf["rope_theta"],
-            tied=hf["tie_word_embeddings"],
-            qkv_bias=(hf["model_type"] == "qwen2"
-                      or bool(hf.get("attention_bias", False))))
-
-
-def leaf_specs(dm: Dims) -> Dict[str, tuple]:
-    """name -> (shape, init), for every weight of the model."""
-    L, d, hd = dm.layers, dm.d, dm.head_dim
-    q, kv = dm.heads * hd, dm.kv_heads * hd
-    s = {
-        "embedding": ((dm.vocab, d), "normal"),
-        "ln_f": ((d,), "ones"),
-        "layers/ln": ((L, d), "ones"),
-        "layers/ln2": ((L, d), "ones"),
-        "layers/wq": ((L, d, q), "scaled"),
-        "layers/wk": ((L, d, kv), "scaled"),
-        "layers/wv": ((L, d, kv), "scaled"),
-        "layers/wo": ((L, q, d), "scaled"),
-        "layers/w_gate": ((L, d, dm.d_ff), "scaled"),
-        "layers/w_up": ((L, d, dm.d_ff), "scaled"),
-        "layers/w_down": ((L, dm.d_ff, d), "scaled"),
-    }
-    if dm.qkv_bias:
-        s["layers/wq_b"] = ((L, q), "zeros")
-        s["layers/wk_b"] = ((L, kv), "zeros")
-        s["layers/wv_b"] = ((L, kv), "zeros")
-    if not dm.tied:
-        s["unembedding"] = ((dm.vocab, d), "normal")
-    return dict(sorted(s.items()))
-
-
-def init_params(dm: Dims, seed: int) -> Dict[str, jax.Array]:
-    specs = leaf_specs(dm)
+def init_params(fam, dm, seed: int) -> Dict[str, jax.Array]:
+    """The weights of the family `fam` at sizes `dm`, from the seed."""
+    specs = fam.leaf_specs(dm)
 
     @jax.jit
     def make(key):
@@ -150,81 +96,47 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def row_loss(p, tokens, labels, dm: Dims, round_to: Optional[str] = None):
-    """Mean cross-entropy of one sequence: tokens, labels [S]."""
-    logits = row_logits(p, tokens, dm, round_to)
+def cross_entropy(logits, labels):
+    """Mean token cross-entropy of logits [S, V] against labels [S]."""
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - gold)
 
 
-def row_logits(p, tokens, dm: Dims, round_to: Optional[str] = None):
-    """Next-token logits [S, V] at every position of one sequence [S]."""
-    rnd = _rounder(round_to)
-
-    def mm(a, b):
-        return jnp.matmul(rnd(a), rnd(b), precision=HIGHEST)
-
-    S, H, KV, hd = tokens.shape[0], dm.heads, dm.kv_heads, dm.head_dim
-    pos = jnp.arange(S)
-    causal = pos[None, :] <= pos[:, None]
-    x = rnd(jnp.take(p["embedding"], tokens, axis=0))
-
-    def layer(x, lp):
-        h = _rms(x, lp["ln"], dm.eps)
-        q, k, v = mm(h, lp["wq"]), mm(h, lp["wk"]), mm(h, lp["wv"])
-        if dm.qkv_bias:
-            q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-        q = _rope(q.reshape(S, H, hd), pos, dm.theta)
-        k = _rope(k.reshape(S, KV, hd), pos, dm.theta)
-        k = jnp.repeat(k, H // KV, axis=1)
-        v = jnp.repeat(v.reshape(S, KV, hd), H // KV, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", rnd(q), rnd(k),
-                       precision=HIGHEST) / np.sqrt(hd)
-        pr = jax.nn.softmax(jnp.where(causal[None], s, -1e30), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", rnd(pr), rnd(v), precision=HIGHEST)
-        x = x + mm(o.reshape(S, H * hd), lp["wo"])
-        h = _rms(x, lp["ln2"], dm.eps)
-        x = x + mm(jax.nn.silu(mm(h, lp["w_gate"])) * mm(h, lp["w_up"]),
-                   lp["w_down"])
-        return x, None
-
-    lps = {k[len("layers/"):]: v for k, v in p.items()
-           if k.startswith("layers/")}
-    x, _ = jax.lax.scan(jax.checkpoint(layer), x, lps)
-    x = _rms(x, p["ln_f"], dm.eps)
-    w = p["embedding"] if dm.tied else p["unembedding"]
-    return jnp.einsum("sd,vd->sv", rnd(x), rnd(w), precision=HIGHEST)
-
-
-@partial(jax.jit, static_argnames=("dm", "round_to"))
-def token_gaps(p, tokens, dm: Dims, served, round_to: Optional[str] = None):
+@partial(jax.jit, static_argnames=("fam", "dm", "round_to"))
+def token_gaps(fam, p, tokens, dm, served, round_to: Optional[str] = None):
     """For a sequence [S] and the tokens [S] a server chose after each of
     its positions: how far below the reference's best logit each chosen
     token's logit lies.  With `round_to`, the chosen tokens are instead
     those the reference below its precision puts first."""
-    ref = row_logits(p, tokens, dm)
+    ref = fam.row_logits(p, tokens, dm)
     if round_to is not None:
-        served = jnp.argmax(row_logits(p, tokens, dm, round_to), axis=-1)
+        served = jnp.argmax(fam.row_logits(p, tokens, dm, round_to), axis=-1)
     chosen = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
     return jnp.max(ref, axis=-1) - chosen
 
 
-@partial(jax.jit, static_argnames=("dm", "round_to"))
-def loss_and_grads(p, tokens, labels, dm: Dims,
+@partial(jax.jit, static_argnames=("fam", "dm", "round_to"))
+def loss_and_grads(fam, p, tokens, labels, dm,
                    round_to: Optional[str] = None):
     """Mean loss over the rows of tokens/labels [B, S] and its gradient,
-    accumulated one row at a time."""
-    vg = jax.value_and_grad(row_loss)
+    accumulated one row at a time, and the rows' statistics summed."""
+    def row(p, tokens, labels):
+        return fam.row_loss(p, tokens, labels, dm, round_to)
 
-    def body(acc, row):
-        l, g = vg(p, row[0], row[1], dm, round_to)
-        return (acc[0] + l, jax.tree.map(jnp.add, acc[1], g)), None
+    vg = jax.value_and_grad(row, has_aux=True)
+
+    def body(acc, r):
+        (l, st), g = vg(p, r[0], r[1])
+        return (acc[0] + l, jax.tree.map(jnp.add, acc[1], g),
+                jax.tree.map(jnp.add, acc[2], st)), None
 
     zeros = jax.tree.map(jnp.zeros_like, p)
-    (loss, grads), _ = jax.lax.scan(body, (jnp.float32(0), zeros),
-                                    (tokens, labels))
+    no_stats = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                            jax.eval_shape(row, p, tokens[0], labels[0])[1])
+    (loss, grads, stats), _ = jax.lax.scan(
+        body, (jnp.float32(0), zeros, no_stats), (tokens, labels))
     n = tokens.shape[0]
-    return loss / n, jax.tree.map(lambda g: g / n, grads)
+    return loss / n, jax.tree.map(lambda g: g / n, grads), stats
 
 
 def _decays(name: str) -> bool:
@@ -259,20 +171,22 @@ def _adamw_leaf(p, g, m, v, scale, lr, bc1, bc2, hp, decays: bool):
     return p - lr * upd, g, m, v
 
 
-def adamw_step(p, m, v, g, count: int, hp: dict):
+def adamw_step(p, m, v, g, count: int, hp: dict, frozen: frozenset):
     """One AdamW update (count = the step's 1-based number) of the device
     params `p` with the device gradient `g`; the moments `m`, `v` stay on
     the host between steps, so that the gradient's computation has the
-    chip's memory to itself.  Returns the new params and moments and the
-    clipped gradient the update used."""
+    chip's memory to itself.  The leaves in `frozen` are neither updated
+    nor counted in the clipped norm.  Returns the new params and moments
+    and the clipped gradient the update used."""
     c = float(count)
-    scale = (_clip_scale(g, hp["grad_clip"]) if hp["grad_clip"] is not None
+    scale = (_clip_scale({k: x for k, x in g.items() if k not in frozen},
+                         hp["grad_clip"]) if hp["grad_clip"] is not None
              else jnp.float32(1))
     lr = jnp.float32(learning_rate(hp, count))
     bc1, bc2 = jnp.float32(1 - hp["b1"] ** c), jnp.float32(1 - hp["b2"] ** c)
     consts = {k: hp[k] for k in ("b1", "b2", "eps", "weight_decay")}
     p, g = dict(p), dict(g)
-    for k in sorted(p):
+    for k in sorted(set(p) - frozen):
         p[k], g[k], mk, vk = _adamw_leaf(p[k], g[k], m[k], v[k], scale, lr,
                                          bc1, bc2, consts,
                                          decays=_decays(k))
@@ -296,14 +210,17 @@ def diff_norms(a, b):
     return leaf_norms({k: a[k] - b[k] for k in a})
 
 
-def run_steps(dm: Dims, seed: int, rows, hp: dict, steps: int,
+def run_steps(fam, dm, seed: int, rows, hp: dict, steps: int,
               round_to: Optional[str] = None, p3_of=None) -> dict:
-    """Train the reference from the seed's weights on `rows` ([steps] of
-    (tokens, labels) host arrays [B, S]).  Returns each step's loss, the
+    """Train the reference of the family `fam` at sizes `dm` from the
+    seed's weights on `rows` ([steps] of (tokens, labels) host arrays
+    [B, S]); after each step's AdamW update, the family's `after_update`
+    gets the step's summed row statistics.  Returns each step's loss, the
     leaf norms of the first clipped gradient, and the leaf norms of the
     parameters' change after `steps` steps, and of `p3_of` (host leaves,
     the same names) minus the initial weights where given."""
-    p = init_params(dm, seed)
+    frozen = fam.non_gradient_leaves(dm)
+    p = init_params(fam, dm, seed)
     prog_change = None
     if p3_of is not None:
         prog_change = jax.device_get(diff_norms(
@@ -313,16 +230,17 @@ def run_steps(dm: Dims, seed: int, rows, hp: dict, steps: int,
     losses, g1 = [], None
     for i in range(steps):
         tokens, labels = rows[i]
-        loss, g = loss_and_grads(p, jnp.asarray(tokens), jnp.asarray(labels),
-                                 dm, round_to)
+        loss, g, stats = loss_and_grads(fam, p, jnp.asarray(tokens),
+                                        jnp.asarray(labels), dm, round_to)
         losses.append(float(loss))
-        p, m, v, g = adamw_step(p, m, v, g, i + 1, hp)
+        p, m, v, g = adamw_step(p, m, v, g, i + 1, hp, frozen)
+        p = fam.after_update(p, stats, dm)
         if i == 0:
             g1 = jax.device_get(leaf_norms(g))
         del g
     del m, v
     # the initial weights again, from the seed, rather than a second copy
     # held through the steps
-    change = jax.device_get(diff_norms(p, init_params(dm, seed)))
+    change = jax.device_get(diff_norms(p, init_params(fam, dm, seed)))
     return {"losses": losses, "grad_norms": g1, "change_norms": change,
             "prog_change_norms": prog_change}

@@ -3,8 +3,12 @@
 A cell of `BENCHMARK.json` names a configuration and a traffic mix.  Each
 is a data file here: `configs/<config>.json`, `traffic/<mix>.json`, and
 `cells/<cell>.json` for what belongs to the pair (its nominal step time
-and the limits of its correctness check).  A per-layer metric is a reader
-`metrics/<metric>.py`.  Nothing here needs an edit when a cell is added.
+and the limits of its correctness check).  A configuration names its
+model family under `program.family`, a module `families/<family>.py`
+(the model's sizes, weights, plain forward pass and work counts); a
+traffic mix names its driver, `drivers/<driver>.py`.  A per-layer metric
+is a reader `metrics/<metric>.py`.  Nothing here needs an edit when a
+cell, a configuration or a family is added.
 """
 from __future__ import annotations
 
@@ -58,6 +62,20 @@ def cell(name: str, bench: dict | None = None) -> Cell:
         params=_load_json(HERE / "cells" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+
+
+def family(conf: dict) -> ModuleType:
+    """The module `families/<family>.py` that the configuration file
+    names under `program.family`; a file that names none, or a family
+    with no module, is an error."""
+    name = conf.get("program", {}).get("family")
+    if name is None:
+        raise KeyError(f"configuration {conf.get('name')!r} names no "
+                       f"program.family")
+    if not (HERE / "families" / f"{name}.py").is_file():
+        raise KeyError(f"no family {name!r}: chipbench/families/{name}.py "
+                       f"is missing")
+    return importlib.import_module(f"chipbench.families.{name}")
 
 
 def metric_reader(name: str) -> ModuleType:

@@ -20,23 +20,43 @@ from chipbench.run import run_cell
 BENCH = spec.benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+# what every family module exposes (`families/__init__.py`), and what a
+# family whose cells serve adds
+FAMILY = ("dims", "program_config", "leaf_specs", "row_logits", "row_loss",
+          "after_update", "non_gradient_leaves", "train_flops_per_step")
+SERVING = ("forward_flops", "paged_attention_bytes")
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_found_by_name(name):
-    """A cell is its config file, traffic file, cell file, driver and the
-    readers of its per-layer metrics; each is found by name alone."""
+    """A cell is its config file, its family's module, traffic file, cell
+    file, driver and the readers of its per-layer metrics; each is found
+    by name alone."""
     c = spec.cell(name, BENCH)
     driver = importlib.import_module(f"chipbench.drivers.{c.traffic['driver']}")
     assert callable(driver.run)
+    fam = spec.family(c.config)
+    for attr in FAMILY + (SERVING if c.traffic["driver"] == "serve" else ()):
+        assert callable(getattr(fam, attr)), attr
+    assert isinstance(fam.TINY, dict) and fam.TINY
     assert "limits" in c.params
     assert {m["name"] for m in c.end_to_end} >= {"setup_s"}
     assert c.per_layer, "every cell reports a per-layer metric"
     for m in c.per_layer:
         assert callable(spec.metric_reader(m["name"]).read)
-    dm = reference.Dims.from_hf(c.config)
+    dm = fam.dims(c.config)
+    hash(dm)                             # a static argument of the reference
     assert dm.vocab % 128 == 0, "a vocabulary the program pads would add " \
         "rows to its softmax that the reference does not have"
+
+
+@pytest.mark.parametrize("program", [{"arch": "smollm-360m"},
+                                     {"family": "no-such-family"}])
+def test_family_is_named_not_defaulted(program):
+    """A configuration that names no family, or one with no module, is an
+    error and not the dense family."""
+    with pytest.raises(KeyError):
+        spec.family({"name": "x", "program": program})
 
 
 def test_benchmark_names_and_files():
@@ -58,24 +78,24 @@ def test_peaks_by_device_kind():
         spec.peaks("cpu")
 
 
-def test_reference_weights_are_the_programs():
-    """The reference's weight recipe gives, from the seed, the weights the
-    program's trainer starts from."""
-    from chipbench.common import flat, model_config
+@pytest.mark.parametrize("name", CELLS)
+def test_reference_weights_are_the_programs(name):
+    """The reference's weight recipe over the cell's family gives, from
+    the seed, the weights the program's trainer starts from."""
+    from chipbench.common import flat
     from repro.models import get_model
     from repro.train import init_state
 
-    for name in CELLS:
-        conf = smoke.tiny_cell(name).config
-        dm = reference.Dims.from_hf(conf)
-        mine = reference.init_params(dm, 1234)
-        prog = flat(jax.jit(lambda k, m=get_model(model_config(conf)):
-                            init_state(m, k))(
-            jax.random.PRNGKey(1234))["params"])
-        assert sorted(mine) == sorted(prog)
-        for k in mine:
-            np.testing.assert_array_equal(np.asarray(mine[k]),
-                                          np.asarray(prog[k]), err_msg=k)
+    conf = smoke.tiny_cell(name).config
+    fam = spec.family(conf)
+    mine = reference.init_params(fam, fam.dims(conf), 1234)
+    prog = flat(jax.jit(lambda k, m=get_model(fam.program_config(conf)):
+                        init_state(m, k))(
+        jax.random.PRNGKey(1234))["params"])
+    assert sorted(mine) == sorted(prog)
+    for k in mine:
+        np.testing.assert_array_equal(np.asarray(mine[k]),
+                                      np.asarray(prog[k]), err_msg=k)
 
 
 @pytest.mark.parametrize("name", CELLS)

@@ -3,21 +3,22 @@ from __future__ import annotations
 
 import pytest
 
-from chipbench import reference, spec, work
+from chipbench import spec
 from chipbench.device_trace import Summary
 from chipbench.common import RunData
+from chipbench.families import dense
 
 
-def _dims(config: str) -> reference.Dims:
-    return reference.Dims.from_hf(spec._load_json(
-        spec.HERE / "configs" / f"{config}.json"))
+def _dims(config: str) -> dense.Dims:
+    conf = spec._load_json(spec.HERE / "configs" / f"{config}.json")
+    return spec.family(conf).dims(conf)
 
 
 def test_smollm_flops():
     dm = _dims("smollm-360m")
     # 32 x (960*960*2 + 2*960*320 + 3*960*2560) + 49152*960 (tied head)
-    assert work.matmul_params(dm) == 32 * 9_830_400 + 47_185_920
-    per_step = work.train_flops_per_token(dm, 1024) * 8 * 1024
+    assert dense.matmul_params(dm) == 32 * 9_830_400 + 47_185_920
+    per_step = dense.train_flops_per_token(dm, 1024) * 8 * 1024
     # 6 * 361.76 M * 8192 + 12 * 32 * 15 * 64 * 1024 * 8192
     assert per_step == pytest.approx(20.874e12, rel=1e-4)
 
@@ -29,19 +30,19 @@ def test_qwen2_7b_2l_flops():
         (2, 3584, 28, 4, 128, 18944, 19200, True, False)
     # 2 x 233,046,016 per layer + 19200*3584 (the untied head; the input
     # embedding is a gather)
-    assert work.matmul_params(dm) == 2 * 233_046_016 + 68_812_800
-    per_step = work.train_flops_per_token(dm, 1024) * 8 * 1024
+    assert dense.matmul_params(dm) == 2 * 233_046_016 + 68_812_800
+    per_step = dense.train_flops_per_token(dm, 1024) * 8 * 1024
     assert per_step == pytest.approx(27.013e12, rel=1e-4)
 
 
 def test_serving_work_counts():
     dm = _dims("smollm-360m")
     # one token of each of 2 sequences against 100 + 300 positions
-    assert work.forward_flops(dm, 2, 400) == \
-        2.0 * work.matmul_params(dm) * 2 + 4.0 * 32 * 15 * 64 * 400
+    assert dense.forward_flops(dm, 2, 400) == \
+        2.0 * dense.matmul_params(dm) * 2 + 4.0 * 32 * 15 * 64 * 400
     # K and V of 400 positions x 5 heads x 64 in bf16, and 2 queries and
     # outputs of 15 x 64, in each of 32 layers
-    assert work.paged_attention_bytes(dm, 2, 400, 2, 2) == \
+    assert dense.paged_attention_bytes(dm, 2, 400, 2, 2) == \
         32 * (2 * 400 * 5 * 64 * 2 + 2 * 2 * 15 * 64 * 2)
 
 
@@ -114,15 +115,16 @@ def test_serving_metrics_arithmetic():
                     kernels=[(kernel, 1e6, 3e6), (kernel, 21e6, 23e6)])
     steps = [(48, 48 * 700), (48, 48 * 701)]
     run = RunData(add_s=[0.02, 0.04], prompt_tokens=4096, pages_shared=224,
-                  page_tokens=16, steps=steps, dims=dm, trace=trace,
+                  page_tokens=16, steps=steps, dims=dm, family=dense,
+                  trace=trace,
                   peaks={"bf16_flops_per_s": 2e14, "hbm_bytes_per_s": 8e11})
     assert read("prefill_ms.serve", run) == pytest.approx(30.0)
     assert read("prefix_shared_share.serve", run) == pytest.approx(87.5)
     assert read("decode_step_device_ms.serve", run) == pytest.approx(10.0)
-    moved = sum(work.paged_attention_bytes(dm, b, c, 2, 2) for b, c in steps)
+    moved = sum(dense.paged_attention_bytes(dm, b, c, 2, 2) for b, c in steps)
     assert read("paged_attention_roofline.serve", run) == \
         pytest.approx(100 * moved / 8e11 / 0.004)
-    flops = sum(work.forward_flops(dm, b, c) for b, c in steps)
+    flops = sum(dense.forward_flops(dm, b, c) for b, c in steps)
     assert read("mfu.serve", run) == pytest.approx(100 * flops / 0.02 / 2e14)
     assert read("device_idle_share.serve", run) == pytest.approx(97.0)
     run.trace.kernels = []
