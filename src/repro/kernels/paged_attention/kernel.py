@@ -7,6 +7,14 @@ in table order, out of the pools in HBM, without ever materializing the
 gathered K/V.  Streaming softmax state (acc/m/l) persists in VMEM scratch
 across a sequence's blocks.
 
+Pools: the kernel reads every layer's pages where the serving engine
+keeps them, in one stacked pool a side, `[L, Hkv, P, T / f, f·D]`
+(`pool_shape`), and takes the layer as a scalar-prefetched operand: its
+DMAs copy `pool.at[layer, :, page]`.  So a decode step hands it the pools
+it updates in place, and no layer's pool is sliced out or relaid.
+One layer's `[Hkv, P, T, D]` pages are read as a stack of one, folded
+(`fold_pages`), at layer 0 (`ops.paged_attention`).
+
 Tiling: grid = (B, ceil(pages_per_seq / pages_per_block)).  A grid step
 takes one block of `pages_per_block` consecutive table entries of one
 sequence, for every KV head: one DMA a page for K and one for V, each
@@ -26,11 +34,13 @@ table's width.
 
 Lanes: a head dim under 128 (64 for SmolLM) would fill half of every
 (8, 128) tile of the pools, and the chip's DMA cannot slice such a page.
-So the wrapper folds `f = 128 // D` consecutive tokens into one row of
-128 lanes (a page [T, D] is read as [T / f, 128]), and the kernel
-computes on rows: query pane c holds the queries in lanes [cD, (c+1)D)
-and zeros elsewhere, so its scores are those of the rows' c-th tokens,
-and its share of p·V lands in the same lanes, which the wrapper sums.
+So the pools fold `f = 128 // D` consecutive tokens into one row of 128
+lanes (a page [T, D] is stored as [T / f, 128]: token `r·f + c` in row
+r, lanes [cD, (c+1)D)), and the kernel computes on rows: query pane c
+holds the queries in lanes [cD, (c+1)D) and zeros elsewhere, so its
+scores are those of the rows' c-th tokens, and its share of p·V lands in
+the same lanes, which the wrapper sums.  Where D is 128, or a page's
+T·D is no whole number of rows, f is 1 and a row is a token.
 Scores, softmax state and p·V are float32 at HIGHEST precision.
 """
 from __future__ import annotations
@@ -66,16 +76,31 @@ def _fold(page_tokens: int, head_dim: int) -> int:
     return 1
 
 
-def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+def pool_shape(layers: int, kv_heads: int, pages: int, page_tokens: int,
+               head_dim: int) -> tuple:
+    """The stacked pool's layout the kernel reads: [L, Hkv, P, T/f, f·D]."""
+    f = _fold(page_tokens, head_dim)
+    return (layers, kv_heads, pages, page_tokens // f, f * head_dim)
+
+
+def fold_pages(pages: jax.Array) -> jax.Array:
+    """[..., T, D] pages as the pool stores them, [..., T/f, f·D]."""
+    t, d = pages.shape[-2:]
+    f = _fold(t, d)
+    return pages.reshape(*pages.shape[:-2], t // f, f * d)
+
+
+def _kernel(table_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, slot_ref, acc_ref, m_ref, l_ref, *,
             ppb: int, blocks: int, batch: int, fold: int, scale: float):
     b = pl.program_id(0)
     i = pl.program_id(1)
-    kv_heads, _, page_rows, lanes = k_hbm.shape
+    _, kv_heads, _, page_rows, lanes = k_hbm.shape
     rows = ppb * page_rows                     # rows of one head's block
     block_tokens = rows * fold
     panes = m_ref.shape[1]                     # query rows of one pane
     length = lens_ref[b]
+    layer = layer_ref[0]
 
     def copies(seq, blk, slot):
         """The block's DMAs: page j of K and V, all heads, into slot."""
@@ -84,7 +109,8 @@ def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             page = table_ref[seq * blocks * ppb + blk * ppb + j]
             for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
                 out.append(pltpu.make_async_copy(
-                    hbm.at[:, page], buf.at[slot, :, j], sems.at[slot]))
+                    hbm.at[layer, :, page], buf.at[slot, :, j],
+                    sems.at[slot]))
         return out
 
     @pl.when((b == 0) & (i == 0))
@@ -167,21 +193,22 @@ def _kernel(table_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
             o_ref[0, h] = jnp.where(length > 0, out, 0.0)
 
 
-def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, page_table: jax.Array,
-                           lengths: jax.Array, *,
+def paged_attention_kernel(q: jax.Array, k_pool: jax.Array,
+                           v_pool: jax.Array, page_table: jax.Array,
+                           lengths: jax.Array, layer: jax.Array, *,
                            interpret: bool = False) -> jax.Array:
-    """q: [B, H, D]; k_pages/v_pages: [Hkv, P, T, D];
-    page_table: [B, PP] int32 (-1 = unused); lengths: [B].
+    """q: [B, H, D]; k_pool/v_pool: every layer's pages, [L, Hkv, P, T/f,
+    f·D] (`pool_shape`); page_table: [B, PP] int32 (-1 = unused);
+    lengths: [B]; layer: the pools' layer to read (an int32 scalar).
     Returns [B, H, D]."""
     b, h, d = q.shape
-    hkv, n_pages, t, _ = k_pages.shape
+    _, hkv, _, rows, lanes = k_pool.shape
+    fold = lanes // d
+    t = rows * fold
     groups = h // hkv
     pp = page_table.shape[1]
-    ppb = pages_per_block(t, hkv, d, k_pages.dtype.itemsize, pp)
+    ppb = pages_per_block(t, hkv, d, k_pool.dtype.itemsize, pp)
     blocks = -(-pp // ppb)
-    fold = _fold(t, d)
-    lanes = fold * d
     panes = -(-groups // 8) * 8                # query rows, whole sublanes
 
     # query pane c: q in lanes [c*D, (c+1)*D), zeros elsewhere
@@ -190,18 +217,16 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
     qf = (qg[:, :, None, :, None, :]
           * jnp.eye(fold, dtype=jnp.float32)[:, None, :, None]
           ).reshape(b, hkv, fold * panes, lanes)
-    kf = k_pages.reshape(hkv, n_pages, t // fold, lanes)
-    vf = v_pages.reshape(hkv, n_pages, t // fold, lanes)
     # unused entries and the padding up to whole blocks read page 0, and
     # every position they hold lies past the length
     table = jnp.maximum(page_table, 0).astype(jnp.int32)
     table = jnp.pad(table, ((0, 0), (0, blocks * ppb - pp))).reshape(-1)
 
-    def q_map(bi, i, tbl, lens):
+    def q_map(bi, i, tbl, lens, lyr):
         return (bi, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, blocks),
         in_specs=[
             pl.BlockSpec((1, hkv, fold * panes, lanes), q_map),
@@ -210,8 +235,8 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, hkv, panes, lanes), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, hkv, ppb, t // fold, lanes), k_pages.dtype),
-            pltpu.VMEM((2, hkv, ppb, t // fold, lanes), v_pages.dtype),
+            pltpu.VMEM((2, hkv, ppb, rows, lanes), k_pool.dtype),
+            pltpu.VMEM((2, hkv, ppb, rows, lanes), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((hkv, panes, lanes), jnp.float32),
@@ -230,6 +255,7 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(table, lengths.astype(jnp.int32), qf, kf, vf)
+    )(table, lengths.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qf, k_pool, v_pool)
     out = out[:, :, :groups].reshape(b, hkv, groups, fold, d).sum(axis=3)
     return out.astype(q.dtype).reshape(b, h, d)

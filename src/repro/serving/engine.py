@@ -7,9 +7,12 @@ decode step consumes the page table DIRECTLY via the Pallas
 `paged_attention` kernel; gathered K/V is never materialized.
 
 Dense-family models (smollm / qwen2 / command-r / mistral / llava-text).
-Layout: pools [L, P, T, Hkv, D] on device; prefill writes a prompt's K/V
-into its pages in one fused step, decode appends one token per step for
-the whole batch.
+Layout: one pool a side on device, [L, Hkv, P, T/f, f·D], the paged
+kernel's own (`pool_shape`): a page of a head is T/f rows of f
+consecutive tokens in 128 lanes (f = 128 // D where the page folds, else
+1).  Prefill writes a prompt's K/V into its pages in one fused step;
+decode appends one token per step for the whole batch, updating the
+donated pools in place, and the kernel reads each layer where it lies.
 """
 from __future__ import annotations
 
@@ -22,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
-                                                  pages_per_block)
+                                                  pages_per_block,
+                                                  pool_shape)
 from repro.models import layers as L
 from repro.util import trace
 from .kv_cache import CacheConfig, PagedKVCache
@@ -59,8 +63,8 @@ class Engine:
             page_tokens=cfg.page_tokens, num_pages=cfg.num_pages,
             max_seqs=cfg.max_seqs, dtype="float32"), allocate=False)
         dt = jnp.dtype(self.mcfg.compute_dtype)
-        shape = (self.mcfg.n_layers, cfg.num_pages, cfg.page_tokens,
-                 self.mcfg.n_kv_heads, hd)
+        shape = pool_shape(self.mcfg.n_layers, self.mcfg.n_kv_heads,
+                           cfg.num_pages, cfg.page_tokens, hd)
         self.k_pool = jnp.zeros(shape, dt)
         self.v_pool = jnp.zeros(shape, dt)
         # reserved scratch page: prefill writes of already-shared prefix
@@ -75,9 +79,10 @@ class Engine:
         # the Pallas kernel compiles for the TPU; any other backend runs
         # it in interpret mode
         self.kernel_interpret = jax.default_backend() != "tpu"
+        # the step updates the pools in place: they are donated
         self._decode = jax.jit(functools.partial(
             _decode_step, cfg=self.mcfg, page_tokens=cfg.page_tokens,
-            interpret=self.kernel_interpret))
+            interpret=self.kernel_interpret), donate_argnums=(1, 2))
 
     # ------------------------------------------------------------ requests
     def add(self, prompt: np.ndarray, max_new: int = 16,
@@ -122,8 +127,10 @@ class Engine:
             return
         with trace.span("engine.cow"):
             new = self.cache._alloc_page()
-            self.k_pool = self.k_pool.at[:, new].set(self.k_pool[:, pid])
-            self.v_pool = self.v_pool.at[:, new].set(self.v_pool[:, pid])
+            self.k_pool = self.k_pool.at[:, :, new].set(
+                self.k_pool[:, :, pid])
+            self.v_pool = self.v_pool.at[:, :, new].set(
+                self.v_pool[:, :, pid])
             self.cache._release_page(pid)
             tbl[page_idx] = new
             self.cache.stats["pages_copied"] += 1
@@ -154,9 +161,10 @@ class Engine:
     def step(self) -> List[int]:
         """One decode step for every active sequence; returns finished ids.
         The step is the span ``engine.step``, whose ``batch`` is 0 where
-        no sequence was active; else it carries the page table's width
-        and the paged kernel's live and spanned blocks (``kv_blocks``,
-        ``kv_blocks_grid``)."""
+        no sequence was active; else it carries the page table's width,
+        the paged kernel's live and spanned blocks (``kv_blocks``,
+        ``kv_blocks_grid``), and ``pools_donated``: 1 where the step
+        consumed the pools it was given in place, else 0."""
         with trace.span("engine.step") as rec:
             active = [r for r in self._requests.values() if not r.done]
             rec.attrs["batch"] = len(active)
@@ -185,10 +193,13 @@ class Engine:
         rec.attrs["table_width"] = width
         rec.attrs["kv_blocks"] = hkv * int(np.sum(-(-(pos + 1) // (ppb * t))))
         rec.attrs["kv_blocks_grid"] = hkv * b * -(-width // ppb)
+        pools_in = (self.k_pool, self.v_pool)
         next_tok, self.k_pool, self.v_pool = self._decode(
             self.params, self.k_pool, self.v_pool,
             jnp.asarray(tokens), jnp.asarray(pos),
             jnp.asarray(np.maximum(tbl_arr, 0)))
+        rec.attrs["pools_donated"] = int(all(p.is_deleted()
+                                             for p in pools_in))
         with trace.span("engine.step.wait"):
             next_tok = np.asarray(next_tok)
         finished = []
@@ -227,14 +238,48 @@ def _qkv(p, y, cfg, pos):
     return q, k, v
 
 
-def _scatter_pages(pool_l, vals, table, positions, page_tokens):
-    """Write vals [B,S,Hkv,D] into pool_l [P,T,Hkv,D] at page slots."""
-    b, s = vals.shape[:2]
-    pages = jnp.take_along_axis(
-        table, positions // page_tokens, axis=1)          # [B,S]
-    slots = positions % page_tokens
-    return pool_l.at[pages.reshape(-1), slots.reshape(-1)].set(
-        vals.reshape(b * s, *vals.shape[2:]))
+def _write_tokens(pool, vals, layer, pages, slots):
+    """Write one token a sequence, vals [B, Hkv, D], into pool [L, Hkv,
+    P, T/f, f·D] at `layer`: sequence b's page `pages[b]` of every head
+    is read, takes the token in row slots[b] // f, lanes from
+    (slots[b] % f)·D, and is written back whole.  The sequences' pages
+    are distinct.  (Windows of whole pages keep the scatter one fusion,
+    in place and in the pool's layout.)"""
+    _, hkv, d = vals.shape
+    rows, lanes = pool.shape[-2:]
+    f = lanes // d
+    at = (layer, jnp.arange(hkv)[None, :], pages[:, None])
+    row = jnp.arange(rows)[:, None]
+    lane = jnp.arange(lanes)[None, :]
+    hit = ((row == (slots // f)[:, None, None, None])
+           & (lane // d == (slots % f)[:, None, None, None]))
+    new = jnp.tile(vals.astype(pool.dtype), (1, 1, f))[:, :, None, :]
+    return pool.at[at].set(jnp.where(hit, new, pool[at]))
+
+
+def _write_prompt(pool, vals, table, start, scratch_page, page_tokens):
+    """Write a prompt's vals [L, S, Hkv, D] into pool at the pages of
+    `table` [PP], whole pages at a time: a page takes positions in
+    [start, S) and keeps the rest as they were.  Positions < `start`
+    belong to a shared (immutable) prefix: a page wholly below it is
+    written to the reserved scratch page instead."""
+    n_layers, s, hkv, d = vals.shape
+    rows, lanes = pool.shape[-2:]
+    pp, t = table.shape[0], page_tokens
+    new = jnp.pad(vals.astype(pool.dtype),
+                  ((0, 0), (0, pp * t - s), (0, 0), (0, 0)))
+    new = new.reshape(n_layers, pp, t, hkv, d).transpose(0, 3, 1, 2, 4)
+    new = new.reshape(n_layers, hkv, pp, rows, lanes)
+    # the position each element of the prompt's pages holds
+    pos = (jnp.arange(pp)[:, None, None] * t
+           + jnp.arange(rows)[None, :, None] * (lanes // d)
+           + jnp.arange(lanes)[None, None, :] // d)       # [PP, R, lanes]
+    li = jnp.arange(n_layers)[:, None, None]
+    h = jnp.arange(hkv)[None, :, None]
+    merged = jnp.where((pos >= start) & (pos < s), new,
+                       pool[li, h, table[None, None, :]])
+    dest = jnp.where((jnp.arange(pp) + 1) * t <= start, scratch_page, table)
+    return pool.at[li, h, dest[None, None, :]].set(merged)
 
 
 def _prefill_step(params, k_pool, v_pool, tokens, table, start,
@@ -262,53 +307,40 @@ def _prefill_step(params, k_pool, v_pool, tokens, table, start,
         x = x + L.swiglu({**p, "ln": ln2}, x, cfg)
         return x, (k, v)
 
-    def scan_body(x, p):
-        x, kv = body(x, p)
-        return x, kv
-
-    x, (ks, vs) = jax.lax.scan(scan_body, x, params["layers"])
+    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = L.unembed(params, x[:, -1:], cfg, None)
     next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    # ks: [L, 1, S, Hkv, D] → scatter each layer; shared-prefix positions
-    # go to the scratch page (their real pages are shared + already filled)
-    shared_mask = pos < start                              # [1, S]
-    eff_table = table
-
-    def write(pool, vals):
-        def per_layer(pool_l, vals_l):
-            pages = jnp.take_along_axis(eff_table, pos // page_tokens,
-                                        axis=1)
-            pages = jnp.where(shared_mask, scratch_page, pages)
-            slots = pos % page_tokens
-            b, s = vals_l.shape[:2]
-            return pool_l.at[pages.reshape(-1), slots.reshape(-1)].set(
-                vals_l.reshape(b * s, *vals_l.shape[2:]))
-        return jax.vmap(per_layer)(pool, vals)
-
-    k_pool = write(k_pool, ks.astype(k_pool.dtype))
-    v_pool = write(v_pool, vs.astype(v_pool.dtype))
+    k_pool = _write_prompt(k_pool, ks[:, 0], table[0], start, scratch_page,
+                           page_tokens)
+    v_pool = _write_prompt(v_pool, vs[:, 0], table[0], start, scratch_page,
+                           page_tokens)
     return next_tok, k_pool, v_pool
 
 
 def _decode_step(params, k_pool, v_pool, tokens, pos, table, *,
                  cfg, page_tokens, interpret):
-    """One token for B sequences against the paged cache."""
+    """One token for B sequences against the paged cache.  The scan
+    carries the pools: each layer scatters its B new K/V rows into them,
+    and the kernel reads that layer of them, so where the caller donates
+    the pools nothing copies them."""
     x = L.embed(params, tokens, cfg, None)
     lengths = pos + 1
+    # this token's page and slot, for every layer
+    pages = jnp.take_along_axis(table, (pos // page_tokens)[:, None],
+                                axis=1)[:, 0]
+    slots = pos % page_tokens
 
-    def body(x, inp):
+    def body(carry, inp):
+        x, k_pool, v_pool = carry
         p, li = inp
         y = L.rms_norm(x, p["ln"], cfg.norm_eps)
         q, k, v = _qkv(p, y, cfg, pos[:, None])
-        # write this token's K/V into its page
-        kp = _scatter_pages(k_pool[li], k.astype(k_pool.dtype), table,
-                            pos[:, None], page_tokens)
-        vp = _scatter_pages(v_pool[li], v.astype(v_pool.dtype), table,
-                            pos[:, None], page_tokens)
+        k_pool = _write_tokens(k_pool, k[:, 0], li, pages, slots)
+        v_pool = _write_tokens(v_pool, v[:, 0], li, pages, slots)
         attn = paged_attention_kernel(
-            q[:, 0], jnp.moveaxis(kp, 2, 0), jnp.moveaxis(vp, 2, 0),
-            table, lengths, interpret=interpret)[:, None]
+            q[:, 0], k_pool, v_pool, table, lengths, li,
+            interpret=interpret)[:, None]
         dt = jnp.dtype(cfg.compute_dtype)
         o = jnp.einsum("bshk,hkd->bsd", attn.astype(dt),
                        L.cast(p["wo"], dt).reshape(cfg.n_heads,
@@ -317,12 +349,12 @@ def _decode_step(params, k_pool, v_pool, tokens, pos, table, *,
         x = x + o
         ln2 = p["ln2"] if "ln2" in p else p["ln"]
         x = x + L.swiglu({**p, "ln": ln2}, x, cfg)
-        return x, (kp, vp)
+        return (x, k_pool, v_pool), None
 
-    n_layers = cfg.n_layers
-    li = jnp.arange(n_layers)
-    x, (kps, vps) = jax.lax.scan(body, x, (params["layers"], li))
+    li = jnp.arange(cfg.n_layers)
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool), (params["layers"], li))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     logits = L.unembed(params, x, cfg, None)
     return (jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32),
-            kps, vps)
+            k_pool, v_pool)

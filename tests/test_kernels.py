@@ -8,6 +8,8 @@ import pytest
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.mamba2_scan import ssd_recurrent_ref, ssd_scan
 from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention.kernel import (fold_pages,
+                                                  paged_attention_kernel)
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
 RNG = jax.random.PRNGKey(7)
@@ -91,8 +93,18 @@ def test_flash_attention_grad_matches_ref():
 def test_paged_attention_sweep(b, h, hkv, d, pages, t, pp, lens, dtype):
     ks = jax.random.split(RNG, 4)
     q = jax.random.normal(ks[0], (b, h, d)).astype(dtype)
-    kp = jax.random.normal(ks[1], (pages, t, hkv, d)).astype(dtype)
-    vp = jax.random.normal(ks[2], (pages, t, hkv, d)).astype(dtype)
+    kp = jax.random.normal(ks[1], (hkv, pages, t, d)).astype(dtype)
+    vp = jax.random.normal(ks[2], (hkv, pages, t, d)).astype(dtype)
+    lengths, tbl = _page_table(b, pages, t, pp, lens)
+    out = paged_attention(q, kp, vp, tbl, lengths, interpret=True)
+    ref = paged_attention_ref(q, kp, vp, tbl, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=_tol(dtype), rtol=_tol(dtype))
+
+
+def _page_table(b, pages, t, pp, lens):
+    """Lengths (`lens`, else drawn) and a table of distinct pages."""
     rng = np.random.RandomState(0)
     lengths = (rng.randint(1, pp * t + 1, size=b) if lens is None
                else np.array(lens)).astype(np.int32)
@@ -101,14 +113,30 @@ def test_paged_attention_sweep(b, h, hkv, d, pages, t, pp, lens, dtype):
     for i, ln in enumerate(lengths):
         for j in range(-(-int(ln) // t)):
             tbl[i, j] = free.pop()
-    out = paged_attention(q, kp, vp, jnp.asarray(tbl),
-                          jnp.asarray(lengths), interpret=True)
-    ref = paged_attention_ref(q, jnp.moveaxis(kp, 2, 0),
-                              jnp.moveaxis(vp, 2, 0),
-                              jnp.asarray(tbl), jnp.asarray(lengths))
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               atol=_tol(dtype), rtol=_tol(dtype))
+    return jnp.asarray(lengths), jnp.asarray(tbl)
+
+
+# the serving engine's stacked pools [L, Hkv, P, T/f, f·D]: the kernel
+# reads the layer it is given, folded (D 64: two tokens a row) or not
+# (D 128), and a sequence of length 0 attends to nothing
+@pytest.mark.parametrize("d", [64, 128], ids=["folded", "unfolded"])
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+def test_paged_attention_layer_of_stacked_pool(d, layer):
+    n_layers, b, h, hkv, pages, t, pp = 5, 3, 6, 2, 24, 16, 6
+    ks = jax.random.split(jax.random.fold_in(RNG, d), 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (n_layers, hkv, pages, t, d), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (n_layers, hkv, pages, t, d), jnp.bfloat16)
+    lengths, tbl = _page_table(b, pages, t, pp, [37, 0, pp * t])
+    out = paged_attention_kernel(q, fold_pages(kp), fold_pages(vp), tbl,
+                                 lengths, jnp.int32(layer), interpret=True)
+    ref = paged_attention_ref(q, kp[layer], vp[layer], tbl, lengths)
+    # the reference's softmax over no position is NaN; the kernel gives 0
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               atol=_tol("bfloat16"), rtol=_tol("bfloat16"))
+    assert not np.asarray(out, np.float32)[~live].any()
 
 
 # ------------------------------------------------------------- SSD scan

@@ -1,6 +1,8 @@
 """Paged serving engine: outputs must match the dense ring-cache decode
 path exactly; prefix forking must share pages (zero-copy) and still
-produce independent continuations."""
+produce independent continuations.  Each runs at pages of 4 tokens (one
+folded row a page, for the smoke's head dim of 32) and of 16 (four rows
+a page), and the decode step consumes the pools it is given."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,9 @@ from repro.configs import get_smoke_config
 from repro.models import get_model
 from repro.serving.engine import Engine, EngineConfig
 from repro.train import make_serve_step
+from repro.util import trace
+
+PAGE_TOKENS = pytest.mark.parametrize("page_tokens", [4, 16])
 
 
 @pytest.fixture(scope="module")
@@ -37,9 +42,11 @@ def _reference_generate(model, params, prompt, max_new):
     return out[:max_new]
 
 
-def test_engine_matches_reference(setup):
+@PAGE_TOKENS
+def test_engine_matches_reference(setup, page_tokens):
     cfg, model, params = setup
-    eng = Engine(model, params, EngineConfig(page_tokens=4, num_pages=128))
+    eng = Engine(model, params, EngineConfig(page_tokens=page_tokens,
+                                             num_pages=128))
     prompt = np.array([5, 9, 2, 7, 11, 3], np.int32)
     sid = eng.add(prompt, max_new=6)
     while not eng._requests[sid].done:
@@ -48,9 +55,11 @@ def test_engine_matches_reference(setup):
     assert eng.result(sid) == ref
 
 
-def test_engine_batched_requests(setup):
+@PAGE_TOKENS
+def test_engine_batched_requests(setup, page_tokens):
     cfg, model, params = setup
-    eng = Engine(model, params, EngineConfig(page_tokens=4, num_pages=256))
+    eng = Engine(model, params, EngineConfig(page_tokens=page_tokens,
+                                             num_pages=256))
     prompts = [np.array(p, np.int32) for p in
                ([1, 2, 3], [10, 20, 30, 40, 50], [7, 7, 7, 7])]
     sids = [eng.add(p, max_new=4) for p in prompts]
@@ -61,13 +70,15 @@ def test_engine_batched_requests(setup):
         assert eng.result(sid) == ref, sid
 
 
-def test_prefix_fork_shares_pages_and_diverges(setup):
+@PAGE_TOKENS
+def test_prefix_fork_shares_pages_and_diverges(setup, page_tokens):
     cfg, model, params = setup
-    eng = Engine(model, params, EngineConfig(page_tokens=4, num_pages=256))
-    base = np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32)   # 2 full pages
+    eng = Engine(model, params, EngineConfig(page_tokens=page_tokens,
+                                             num_pages=256))
+    base = np.resize(np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32),
+                     2 * page_tokens)                     # 2 full pages
     a = eng.add(base, max_new=4)
-    allocated_before = eng.cache.stats["pages_allocated"]
-    # fork with the same 8-token (page-aligned) prefix + 2 extra tokens
+    # fork with the same page-aligned prefix + 2 extra tokens
     b = eng.add(np.concatenate([base, [8, 8]]).astype(np.int32),
                 max_new=4, fork_from=a)
     assert eng.cache.stats["pages_shared"] >= 2        # prefix pages shared
@@ -79,10 +90,13 @@ def test_prefix_fork_shares_pages_and_diverges(setup):
     assert eng.result(b) == ref_b
 
 
-def test_fork_mid_page_cow(setup):
+@PAGE_TOKENS
+def test_fork_mid_page_cow(setup, page_tokens):
     cfg, model, params = setup
-    eng = Engine(model, params, EngineConfig(page_tokens=4, num_pages=256))
-    base = np.array([3, 1, 4, 1, 5, 9], np.int32)      # 1.5 pages
+    eng = Engine(model, params, EngineConfig(page_tokens=page_tokens,
+                                             num_pages=256))
+    base = np.resize(np.array([3, 1, 4, 1, 5, 9], np.int32),
+                     page_tokens + page_tokens // 2)      # 1.5 pages
     a = eng.add(base, max_new=3)
     b = eng.add(np.concatenate([base, [2, 2]]).astype(np.int32),
                 max_new=3, fork_from=a)
@@ -93,3 +107,14 @@ def test_fork_mid_page_cow(setup):
                                                 list(base), 3)
     assert eng.result(b) == _reference_generate(
         model, params, list(base) + [2, 2], 3)
+
+
+def test_step_donates_pools(setup):
+    cfg, model, params = setup
+    eng = Engine(model, params, EngineConfig(page_tokens=16, num_pages=64))
+    eng.add(np.array([5, 9, 2, 7, 11], np.int32), max_new=3)
+    pools_in = (eng.k_pool, eng.v_pool)
+    eng.step()
+    assert all(p.is_deleted() for p in pools_in)
+    assert trace.records("engine.step")[-1].attrs["pools_donated"] == 1
+    assert not eng.k_pool.is_deleted() and not eng.v_pool.is_deleted()

@@ -1,13 +1,16 @@
 """The main path's programs compile for a TPU v5e that is described, not
 attached: the Pallas kernels at the widths `chip_smoke.py` serves and
-trains at, the full-width decode step, and the full-width train step,
-which must fit one chip's HBM.  Nothing here runs; a compile that the
-chip's compiler refuses (tiling, VMEM, HBM) fails the test.
+trains at, the full-width decode step, which must update its donated
+pools in place, and the full-width train step, which must fit one chip's
+HBM.  Nothing here runs; a compile that the chip's compiler refuses
+(tiling, VMEM, HBM) fails the test.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every pytest worker imports this file."""
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 import chip_smoke as smoke
 from repro.configs import get_config
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.paged_attention.kernel import paged_attention_kernel
+from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
+                                                  pool_shape)
 from repro.models import get_model
 from repro.serving.engine import _decode_step
 from repro.train import abstract_state, make_train_step
@@ -77,12 +81,13 @@ def test_paged_attention_kernel_compiles(one_chip, dtype, batch, pages,
                                          pool_pages):
     cfg = get_config(smoke.ARCH)
     d, hkv = cfg.head_dim_, cfg.n_kv_heads
-    pool = (hkv, pool_pages, smoke.PAGE_TOKENS, d)
+    pool = pool_shape(cfg.n_layers, hkv, pool_pages, smoke.PAGE_TOKENS, d)
     compiled = jax.jit(paged_attention_kernel).lower(
         _spec(one_chip, (batch, cfg.n_heads, d), dtype),
         _spec(one_chip, pool, dtype), _spec(one_chip, pool, dtype),
         _spec(one_chip, (batch, pages), "int32"),
-        _spec(one_chip, (batch,), "int32")).compile()
+        _spec(one_chip, (batch,), "int32"),
+        _spec(one_chip, (), "int32")).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -102,23 +107,50 @@ def test_flash_attention_compiles(one_chip, direction):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_full_width_decode_step_compiles(one_chip):
+def _pool_copies(hlo: str, pool_pages: int, layer_elems: int) -> list:
+    """The shapes of the arrays that copy instructions write which have
+    a dimension of the pools' `pool_pages` pages and hold one layer's
+    pool or more.  (A copy of a parameter, such as the embedding's into
+    another memory space, has no such dimension.)"""
+    shapes = [[int(n) for n in dims.split(",") if n]
+              for line in hlo.splitlines()
+              if re.search(r" copy(-start)?\(", line)
+              for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                     line.split(" copy")[0])]
+    return [s for s in shapes
+            if pool_pages in s and math.prod(s) >= layer_elems]
+
+
+@pytest.mark.parametrize("batch,pages,pool_pages", [
+    pytest.param(smoke.SERVE_BATCH, smoke.SERVE_TABLE_PAGES,
+                 smoke.NUM_PAGES, id="smoke"),
+    # the serving benchmark's batch, widest table and pool
+    pytest.param(48, 96, 5120, id="serve"),
+])
+def test_full_width_decode_step_compiles(one_chip, batch, pages,
+                                         pool_pages):
     cfg = get_config(smoke.ARCH)
     model = get_model(cfg)
-    pool = (cfg.n_layers, smoke.NUM_PAGES, smoke.PAGE_TOKENS,
-            cfg.n_kv_heads, cfg.head_dim_)
-    b = smoke.SERVE_BATCH
+    t, hkv, d = smoke.PAGE_TOKENS, cfg.n_kv_heads, cfg.head_dim_
+    pool = pool_shape(cfg.n_layers, hkv, pool_pages, t, d)
     step = jax.jit(functools.partial(
-        _decode_step, cfg=cfg, page_tokens=smoke.PAGE_TOKENS,
-        interpret=False))
+        _decode_step, cfg=cfg, page_tokens=t, interpret=False),
+        donate_argnums=(1, 2))
     compiled = step.lower(
         _on(one_chip, model.abstract_params()),
         _spec(one_chip, pool, cfg.compute_dtype),
         _spec(one_chip, pool, cfg.compute_dtype),
-        _spec(one_chip, (b, 1), "int32"), _spec(one_chip, (b,), "int32"),
-        _spec(one_chip, (b, smoke.SERVE_TABLE_PAGES), "int32")).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        _spec(one_chip, (batch, 1), "int32"),
+        _spec(one_chip, (batch,), "int32"),
+        _spec(one_chip, (batch, pages), "int32")).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
     assert _hbm_need(compiled) < HBM_BYTES
+    # the step updates both pools in place: they are its outputs' buffers,
+    # and no copy writes a pool or a layer of one
+    pool_bytes = math.prod(pool) * jnp.dtype(cfg.compute_dtype).itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+    assert _pool_copies(hlo, pool_pages, hkv * pool_pages * t * d) == []
 
 
 def test_full_width_train_step_fits_one_chip(one_chip):

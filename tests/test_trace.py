@@ -219,7 +219,7 @@ def test_engine_add_and_step_spans():
     step, idle = trace.records("engine.step")
     # 10 tokens in one block of the table's 3 pages, for the one KV head
     assert step.attrs == {"batch": 1, "table_width": 3, "kv_blocks": 1,
-                          "kv_blocks_grid": 1}
+                          "kv_blocks_grid": 1, "pools_donated": 1}
     assert idle.attrs == {"batch": 0} and not trace.children(idle)
     (prepare,) = trace.children(step, "engine.step.prepare")
     (wait,) = trace.children(step, "engine.step.wait")
@@ -242,4 +242,4 @@ def test_engine_step_kv_blocks_end_mid_block():
     (step,) = trace.records("engine.step")
     assert step.attrs == {"batch": 2, "table_width": 3,
                           "kv_blocks": 2 * (1 + 2),
-                          "kv_blocks_grid": 2 * 2 * 2}
+                          "kv_blocks_grid": 2 * 2 * 2, "pools_donated": 1}
